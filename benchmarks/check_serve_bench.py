@@ -5,8 +5,8 @@
 
 Asserts the bench doc is machine-readable — one ``prefill`` and one
 ``decode`` row per per-phase variant, every row carrying the keys
-downstream tooling reads (``kernel_used`` included, so jnp/VMEM
-fallbacks stay visible in the perf trajectory) — and that nm24 prefill
+downstream tooling reads (``kernel_used`` included, so a jnp path stays
+visible in the perf trajectory) — and that nm24 prefill
 has not regressed past the given ratio of dense prefill. The default
 2.0 is the CI guard on the interpret/jnp path; the committed repo-root
 bench holds the tighter 1.5 acceptance ratio.

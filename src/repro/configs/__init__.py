@@ -37,7 +37,24 @@ def get_tiny(name: str) -> ArchConfig:
     return TINY[get(name).name]
 
 
+def resolve(name: str, *, tiny: bool = False,
+            n_layers: int | None = None) -> ArchConfig:
+    """The published (or tiny) config with its depth cut to ``n_layers``
+    when given; every other field keeps its value."""
+    cfg = get_tiny(name) if tiny else get(name)
+    return cfg if n_layers is None else cfg.replace(n_layers=n_layers)
+
+
+def describe(cfg: ArchConfig) -> str:
+    """One line naming the depth a run uses next to the published one."""
+    return (f"config {cfg.name}: n_layers={cfg.n_layers} (published "
+            f"{get(cfg.name).n_layers}) d_model={cfg.d_model} "
+            f"d_ff={cfg.d_ff} heads={cfg.n_heads}/{cfg.n_kv_heads} "
+            f"vocab={cfg.vocab_size} dtype={cfg.dtype}")
+
+
 __all__ = [
     "ARCHS", "ASSIGNED", "TINY", "ArchConfig", "SHAPES", "ShapeCell",
-    "SUBQUADRATIC", "cell_skips", "get", "get_tiny", "shape_cells",
+    "SUBQUADRATIC", "cell_skips", "describe", "get", "get_tiny",
+    "resolve", "shape_cells",
 ]

@@ -44,6 +44,11 @@ import jax
 import jax.numpy as jnp
 
 INVALID = jnp.float32(jnp.inf)  # +inf sentinel for masked-out candidates
+# float32 contractions of the objective (c, row losses) run at full float32
+# precision: the TPU's default computes them in one bfloat16 pass, and
+# errors that size swamp the ΔL of a single swap (the loss bookkeeping
+# L + ΣΔL then drifts below zero)
+HIGHEST = jax.lax.Precision.HIGHEST
 _BIG_I32 = jnp.int32(2**30)     # index sentinel that loses every tie-break
 
 
@@ -53,13 +58,27 @@ def correlation_vector(w: jnp.ndarray, m: jnp.ndarray, G: jnp.ndarray) -> jnp.nd
     w, m: (R, d_in); G: (d_in, d_in) -> c: (R, d_in), fp32.
     """
     wp = ((1.0 - m) * w).astype(jnp.float32)
-    return wp @ G.astype(jnp.float32).T  # G symmetric; .T keeps layout intent
+    # G symmetric; .T keeps layout intent
+    return jnp.matmul(wp, G.astype(jnp.float32).T, precision=HIGHEST)
 
 
 def row_loss(w: jnp.ndarray, m: jnp.ndarray, G: jnp.ndarray) -> jnp.ndarray:
     """Exact per-row loss L = (w - m⊙w)^T G (w - m⊙w). (R,)."""
-    wp = ((1.0 - m) * w).astype(jnp.float32)
-    return jnp.einsum("ri,ij,rj->r", wp, G.astype(jnp.float32), wp)
+    return quad_form((1.0 - m) * w, G)
+
+
+def quad_form(x: jnp.ndarray, G: jnp.ndarray) -> jnp.ndarray:
+    """Per-row x_r^T G x_r for x: (R, d), G: (d, d) symmetric. (R,).
+
+    Summed as Σ_j x_j (x G)_j from a materialized HIGHEST matmul. The
+    barrier keeps the TPU compiler from fusing the multiply-reduce into
+    the matmul: fused, a v5e returned -1281 for a row whose float64 loss
+    is 660 (jitted einsum or matmul alike, at any precision setting),
+    while the unfused sum is within 5e-7."""
+    x = x.astype(jnp.float32)
+    xg = jax.lax.optimization_barrier(
+        jnp.matmul(x, G.astype(jnp.float32), precision=HIGHEST))
+    return jnp.sum(x * xg, axis=-1)
 
 
 def swap_scores(w: jnp.ndarray, m: jnp.ndarray, c: jnp.ndarray, g_diag: jnp.ndarray):
@@ -147,40 +166,52 @@ def best_swap_chunked(w, m, c, G, *, chunk: int = 512):
     return best, best_u, best_p
 
 
+def _nm_pairs(w, m, c, G, block: int):
+    """Within-block ΔL as a (block, block, R, nb) stack.
+
+    Entry [i, j, r, b] is ΔL of the swap u = b·block + i, p = b·block + j
+    in row r. Only the block-diagonal of G is read: O(d_in · block) per
+    row. The block count stays on the minor axis — the TPU tiles the
+    minor axis to 128 lanes, so a (..., block)-minor array costs
+    128/block times its size in HBM.
+    """
+    R, d_in = w.shape
+    nb = d_in // block
+    a, b = swap_scores(w, m, c, jnp.diagonal(G))
+    # (block, R, nb): row i holds in-block offset i of every block
+    split = lambda x: x.reshape(R, nb, block).transpose(2, 0, 1)
+    a, b, w32 = split(a), split(b), split(w.astype(jnp.float32))
+    G32 = G.astype(jnp.float32)
+    base = jnp.arange(nb) * block
+    ii = jnp.arange(block)
+    g = G32[base + ii[:, None, None], base + ii[None, :, None]]
+    g = g[:, :, None, :]                                  # (i, j, 1, nb)
+    return ((a[:, None] + b[None, :])
+            - 2.0 * (w32[:, None] * w32[None, :]) * g)
+
+
+def _interleave(x):
+    """(block, R, nb) -> (R, nb · block): back to column order."""
+    block, R, nb = x.shape
+    return x.transpose(1, 2, 0).reshape(R, nb * block)
+
+
 def best_swap_nm(w, m, c, G, *, block: int):
     """Best within-block swap for N:M sparsity (paper §2.2).
 
     Swaps are restricted to the same M-block, so only the block-diagonal of
-    G is needed: O(d_in · block) per row instead of O(d_in²).
+    G is needed: O(d_in · block) per row instead of O(d_in²). Ties go to
+    the lowest u, then the lowest p.
     """
-    R, d_in = w.shape
-    nb = d_in // block
-    g_diag = jnp.diagonal(G)
-    a, b = swap_scores(w, m, c, g_diag)            # (R, d)
-    a = a.reshape(R, nb, block)
-    b = b.reshape(R, nb, block)
-    w32 = w.astype(jnp.float32).reshape(R, nb, block)
-    # Block-diagonal gather of G: (nb, block, block)
-    Gb = _block_diag(G, block)
-    inter = 2.0 * jnp.einsum("rnu,rnp,nup->rnup", w32, w32, Gb)
-    dl = a[..., :, None] + b[..., None, :] - inter  # (R, nb, block, block)
-    flat = dl.reshape(R, nb * block * block)
-    idx = jnp.argmin(flat, axis=1)
-    val = jnp.take_along_axis(flat, idx[:, None], axis=1)[:, 0]
-    blk = idx // (block * block)
-    rem = idx % (block * block)
-    u_i = (blk * block + rem // block).astype(jnp.int32)
-    p_i = (blk * block + rem % block).astype(jnp.int32)
+    dl = _nm_pairs(w, m, c, G, block)
+    # best p per u; argmin keeps the first index, so ties keep low p
+    best = _interleave(jnp.min(dl, axis=1))              # (R, d_in) by u
+    p_off = _interleave(jnp.argmin(dl, axis=1).astype(jnp.int32))
+    u_i = jnp.argmin(best, axis=1).astype(jnp.int32)
+    val = jnp.take_along_axis(best, u_i[:, None], axis=1)[:, 0]
+    p_i = u_i - u_i % block + jnp.take_along_axis(
+        p_off, u_i[:, None], axis=1)[:, 0]
     return val, u_i, p_i
-
-
-def _block_diag(G: jnp.ndarray, block: int) -> jnp.ndarray:
-    """Extract (nb, block, block) block-diagonal of G."""
-    d = G.shape[0]
-    nb = d // block
-    G4 = G.astype(jnp.float32).reshape(nb, block, nb, block)
-    idx = jnp.arange(nb)
-    return G4[idx, :, idx, :]
 
 
 # ---------------------------------------------------------------------------
@@ -271,24 +302,18 @@ def topk_swaps_chunked(w, m, c, G, *, k: int, chunk: int = 512):
 def topk_swaps_nm(w, m, c, G, *, block: int, k: int):
     """k best within-block candidate swaps for N:M sparsity.
 
-    Same block-diagonal contraction as ``best_swap_nm`` — only
-    O(d_in·block) of G is touched per row.
+    Same block-diagonal bands as ``best_swap_nm`` — only O(d_in·block) of
+    G is touched per row. Per p the best u is the lowest on ties; the k
+    best p follow ``lax.top_k`` order.
     """
     R, d_in = w.shape
-    nb = d_in // block
     k = min(k, d_in)
-    g_diag = jnp.diagonal(G)
-    a, b = swap_scores(w, m, c, g_diag)
-    a = a.reshape(R, nb, block)
-    b = b.reshape(R, nb, block)
-    w32 = w.astype(jnp.float32).reshape(R, nb, block)
-    Gb = _block_diag(G, block)
-    inter = 2.0 * (w32[..., :, None] * w32[..., None, :]) * Gb[None]
-    dl = a[..., :, None] + b[..., None, :] - inter  # (R, nb, block, block)
-    vals_p = jnp.min(dl, axis=2).reshape(R, d_in)   # global p order
-    u_loc = jnp.argmin(dl, axis=2).astype(jnp.int32)            # (R, nb, B)
-    u_glob = (u_loc + block * jnp.arange(nb, dtype=jnp.int32)[None, :, None]
-              ).reshape(R, d_in)
+    dl = _nm_pairs(w, m, c, G, block)
+    # best u per p; argmin keeps the first index, so ties keep low u
+    vals_p = _interleave(jnp.min(dl, axis=0))            # (R, d_in) by p
+    pos = jnp.arange(d_in, dtype=jnp.int32)
+    u_glob = pos - pos % block + _interleave(
+        jnp.argmin(dl, axis=0).astype(jnp.int32))
     neg, p_idx = jax.lax.top_k(-vals_p, k)
     u_idx = jnp.take_along_axis(u_glob, p_idx, axis=1)
     return -neg, u_idx, p_idx.astype(jnp.int32)
@@ -383,16 +408,23 @@ def apply_commits(w, m, c, G, acc, dls, u, p):
     R, k = acc.shape
     w32 = w.astype(jnp.float32)
     G32 = G.astype(jnp.float32)
-    for t in range(k):                           # static unroll, k small
+
+    # a loop, not an unroll: each step's two gathered (R, d) columns die
+    # before the next step's are built
+    def body(t, mc):
+        m, c = mc
         sel = acc[:, t][:, None]
-        wu_t = jnp.take_along_axis(w32, u[:, t:t + 1], axis=1)
-        wp_t = jnp.take_along_axis(w32, p[:, t:t + 1], axis=1)
-        gu = G32[:, u[:, t]].T                   # (R, d) columns G_{:, u_t}
-        gp = G32[:, p[:, t]].T
+        u_t, p_t = u[:, t], p[:, t]
+        wu_t = jnp.take_along_axis(w32, u_t[:, None], axis=1)
+        wp_t = jnp.take_along_axis(w32, p_t[:, None], axis=1)
+        gu = G32[:, u_t].T                       # (R, d) columns G_{:, u_t}
+        gp = G32[:, p_t].T
         c = c + sel * (wu_t * gu - wp_t * gp)
-        flip = (jax.nn.one_hot(p[:, t], m.shape[1], dtype=m.dtype)
-                - jax.nn.one_hot(u[:, t], m.shape[1], dtype=m.dtype))
-        m = m + sel.astype(m.dtype) * flip
+        flip = (jax.nn.one_hot(p_t, m.shape[1], dtype=m.dtype)
+                - jax.nn.one_hot(u_t, m.shape[1], dtype=m.dtype))
+        return m + sel.astype(m.dtype) * flip, c
+
+    m, c = jax.lax.fori_loop(0, k, body, (m, c))
     return m, c, jnp.sum(dls, axis=1), jnp.sum(acc, axis=1).astype(jnp.int32)
 
 

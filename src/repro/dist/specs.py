@@ -7,6 +7,8 @@ launcher pass to jit" — one spec per pytree leaf, derived from shapes:
 * weights — ("model", "data") on the two largest dims (tensor parallel +
   FSDP), each guarded by divisibility against the mesh axis size; anything
   that does not divide stays replicated (the granite-moe vocab 49155 case);
+* packed weights — output rows over every mesh axis (``packed_pspec``),
+  the slice each device's sparse kernel serves;
 * optimizer state — same rule as the matching param (AdamW m/v mirror the
   param tree), scalars replicated;
 * decode caches — batch dim over the data-parallel axes, KV-head dim over
@@ -19,6 +21,7 @@ a 512-chip mesh without touching device state.
 """
 from __future__ import annotations
 
+import dataclasses
 import math
 from typing import Any, Mapping, Sequence
 
@@ -26,6 +29,7 @@ import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
+from repro.core.packed import PackedWeight
 
 
 def _dp_axes(mesh_shape: Mapping[str, int]) -> tuple[str, ...]:
@@ -77,13 +81,30 @@ def leaf_pspec(path: Sequence[str], shape: Sequence[int], cfg: ArchConfig,
     return P(*assign)
 
 
+def packed_pspec(shape: Sequence[int], mesh) -> P:
+    """Packed-leaf spec: output rows (dim -2) over every mesh axis.
+
+    ``kernels.spmm`` runs the Pallas kernel per device on exactly this
+    row slice, so the packed values/idx never move between calls. Rows
+    that do not divide stay replicated (spmm then refuses the mesh).
+    """
+    rows = tuple(mesh.axis_names)
+    if shape[-2] % math.prod(mesh.shape.values()):
+        rows = None
+    return P(*([None] * (len(shape) - 2)), rows, None)
+
+
 def param_pspecs(cfg: ArchConfig, params: Any, mesh, *,
                  fsdp: bool = True) -> Any:
     """Spec tree matching ``params`` leaf-for-leaf."""
+    def leaf(p, x):
+        if isinstance(x, PackedWeight):
+            spec = packed_pspec(x.values.shape, mesh)
+            return dataclasses.replace(x, values=spec, idx=spec)
+        return leaf_pspec(_path_names(p), x.shape, cfg, mesh, fsdp=fsdp)
+
     return jax.tree_util.tree_map_with_path(
-        lambda p, leaf: leaf_pspec(_path_names(p), leaf.shape, cfg, mesh,
-                                   fsdp=fsdp),
-        params)
+        leaf, params, is_leaf=lambda x: isinstance(x, PackedWeight))
 
 
 def state_pspecs(cfg: ArchConfig, state: Any, mesh, *,

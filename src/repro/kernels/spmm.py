@@ -16,11 +16,14 @@ materialized a full (TO, d_in) fp32 scratch per output tile and capped
 * grid ``(T/TT, d_out/TO, K/TS, d_in/TD)`` — token stripes outermost,
   then output tiles, with the packed-slot x reduction axes innermost;
 * each (slot-tile, d-tile) step expands its slot block into a small
-  (TO, TD) fp32 sub-tile in VMEM (slot-indexed one-hot accumulation —
-  out-of-tile columns fall out of the iota match) and feeds the MXU
-  directly: ``acc += x_tile @ sub_tileᵀ`` with a persistent (TT, TO)
-  fp32 accumulator. No (TO, d_in) scratch ever exists, so there is no
-  ``d_in`` cap — wide layers tile instead of falling back;
+  (TD, TO) fp32 sub-tile (the weight tile transposed) in VMEM
+  (slot-indexed one-hot accumulation — out-of-tile columns fall out of
+  the iota match) and feeds the MXU directly: ``acc += x_tile @
+  sub_tile`` with a persistent (TT, TO) fp32 accumulator. The slot
+  block is first copied slot-major, so the per-slot loop reads one
+  sublane row at a dynamic offset; a width-1 dynamic *lane* slice is
+  refused by the TPU compiler. No (TO, d_in) scratch ever exists, so
+  there is no ``d_in`` cap — wide layers tile;
 * ``nm24`` slots are column-sorted by construction, so the slot block
   for d-tile ``di`` is the *static* slice ``[di·TD·n/m, (di+1)·TD·n/m)``
   — the slot grid axis collapses to 1 and expansion work drops from
@@ -47,24 +50,26 @@ BLAS matmul, amortizing the O(d_out·d_in) expansion over all T tokens —
 the same amortization the Pallas kernel gets from its token stripes.
 
 Kernel selection is logged at trace time (``record_dispatch``) so the
-serving engine can report which path actually ran, and any VMEM-driven
-fallback warns once per offending shape instead of silently degrading.
+serving engine can report which path actually ran. Tiles whose VMEM
+estimate passes ``_VMEM_BOUND`` raise: the kernel never silently hands
+a call to the jnp path.
 """
 from __future__ import annotations
 
 import contextlib
 import functools
-import warnings
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 from repro.core.packed import PackedWeight
 
-# VMEM budget per grid step (double-buffered operands + scratch); ~16 MiB
-# per core physically — leave headroom for the pipeline and the compiler.
+# VMEM budget per grid step (double-buffered operands + scratch). Mosaic's
+# default scoped-VMEM limit on v5e is 16 MiB; leave headroom for the
+# compiler's own buffers.
 _VMEM_BOUND = 12 * 2**20
 
 # default tile shapes (see _plan): token stripe, output rows, d_in columns,
@@ -121,11 +126,10 @@ def apply_epilogue(y, bias=None, act: str | None = None):
 
 
 # ---------------------------------------------------------------------------
-# dispatch bookkeeping: trace-time records + warn-once fallbacks
+# dispatch bookkeeping: trace-time records
 # ---------------------------------------------------------------------------
 
 _DISPATCH_LOG: list | None = None
-_WARNED: set = set()
 
 
 @contextlib.contextmanager
@@ -134,14 +138,18 @@ def record_dispatch():
 
     Kernel selection is static (shapes are trace-time constants), so a
     list appended to during tracing is exact. Yields the list; each
-    entry: {"kernel", "fmt", "T", "d_out", "d_in", "reason"}.
+    entry: {"kernel", "fmt", "T", "d_out", "d_in", "reason"}. Blocks
+    nest: an enclosing block also receives the inner block's records.
     """
     global _DISPATCH_LOG
     prev, _DISPATCH_LOG = _DISPATCH_LOG, []
+    rec = _DISPATCH_LOG
     try:
-        yield _DISPATCH_LOG
+        yield rec
     finally:
         _DISPATCH_LOG = prev
+        if prev is not None:
+            prev.extend(rec)
 
 
 def _record(kernel: str, fmt: str, T: int, d_out: int, d_in: int,
@@ -150,19 +158,6 @@ def _record(kernel: str, fmt: str, T: int, d_out: int, d_in: int,
         _DISPATCH_LOG.append({"kernel": kernel, "fmt": fmt, "T": T,
                               "d_out": d_out, "d_in": d_in,
                               "reason": reason})
-
-
-def _warn_vmem_fallback(d_in: int, tiles: tuple, est: int) -> None:
-    key = (d_in, tiles)
-    if key in _WARNED:
-        return
-    _WARNED.add(key)
-    warnings.warn(
-        f"spmm: pallas kernel with tiles (tile_t, tile_o, tile_d)={tiles} "
-        f"needs ~{est / 2**20:.1f} MiB VMEM per grid step for d_in={d_in} "
-        f"(bound {_VMEM_BOUND / 2**20:.0f} MiB) — falling back to the jnp "
-        f"path; shrink the tiles to keep the fused kernel",
-        RuntimeWarning, stacklevel=3)
 
 
 # ---------------------------------------------------------------------------
@@ -200,8 +195,8 @@ def _plan(T: int, d_in: int, K: int, nm: tuple[int, int] | None, *,
 
 def _vmem_bytes(plan: dict, x_itemsize: int, v_itemsize: int) -> int:
     """Estimated VMEM per grid step: double-buffered operand blocks plus
-    the fp32 accumulator + expansion scratch (the fallback criterion —
-    and the quantity the boundary test pins at ``_VMEM_BOUND``)."""
+    the fp32 accumulator, the expansion sub-tile and the slot-major
+    copies (the quantity the boundary test pins at ``_VMEM_BOUND``)."""
     tt, to = plan["tile_t"], plan["tile_o"]
     td, ts = plan["tile_d"], plan["tile_s"]
     x_blk = tt * td * x_itemsize
@@ -209,7 +204,7 @@ def _vmem_bytes(plan: dict, x_itemsize: int, v_itemsize: int) -> int:
     c_blk = to * ts * 4
     o_blk = tt * to * 4
     b_blk = to * 4
-    scratch = tt * to * 4 + to * td * 4
+    scratch = tt * to * 4 + td * to * 4 + 2 * ts * to * 4
     return 2 * (x_blk + v_blk + c_blk + o_blk + b_blk) + scratch
 
 
@@ -217,14 +212,17 @@ def _vmem_bytes(plan: dict, x_itemsize: int, v_itemsize: int) -> int:
 # kernel
 # ---------------------------------------------------------------------------
 
-def _spmm_kernel(x_ref, v_ref, c_ref, b_ref, o_ref, acc_ref, sub_ref, *,
-                 n_slots: int, tile_d: int, act: str | None):
+def _spmm_kernel(x_ref, v_ref, c_ref, b_ref, o_ref, acc_ref, sub_ref,
+                 vt_ref, ct_ref, *, n_slots: int, tile_d: int,
+                 act: str | None):
     """One fused reduction step of ``y = act(x @ Wᵀ + b)``.
 
     x_ref: (TT, TD) token stripe x d-tile; v_ref/c_ref: (TO, TS) packed
     values + absolute columns for this slot tile; b_ref: (1, TO) fp32
     bias; o_ref: (TT, TO); acc_ref: persistent fp32 accumulator;
-    sub_ref: (TO, TD) fp32 expansion scratch, rebuilt per step.
+    sub_ref: (TD, TO) fp32 expansion scratch (the weight tile
+    transposed), rebuilt per step; vt_ref/ct_ref: (TS, TO) slot-major
+    copies of the slot block.
     """
     si, di = pl.program_id(2), pl.program_id(3)
     first = jnp.logical_and(si == 0, di == 0)
@@ -235,27 +233,31 @@ def _spmm_kernel(x_ref, v_ref, c_ref, b_ref, o_ref, acc_ref, sub_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # expand this slot block into the (TO, TD) sub-tile: a slot whose
+    # slot-major copies: the slot loop below then reads one sublane row
+    # per slot. A width-1 dynamic *lane* slice of the (TO, TS) block is
+    # what the TPU compiler refuses.
+    ct_ref[...] = (c_ref[...] - di * tile_d).T
+    vt_ref[...] = v_ref[...].astype(jnp.float32).T
+
+    # expand the slot block into the (TD, TO) sub-tile: a slot whose
     # column lies outside [di·TD, (di+1)·TD) never matches the iota, so
     # the same masked accumulation serves aligned nm24 blocks, arbitrary
     # gathered slots, and zero-padding alike. Kept columns are unique
     # per row -> the add is an exact scatter.
     sub_ref[...] = jnp.zeros_like(sub_ref)
-    iota = jax.lax.broadcasted_iota(jnp.int32, sub_ref.shape, 1)
-    base = di * tile_d
+    iota = jax.lax.broadcasted_iota(jnp.int32, sub_ref.shape, 0)
 
     def body(s, carry):
-        local = c_ref[:, pl.ds(s, 1)] - base               # (TO, 1)
-        val = v_ref[:, pl.ds(s, 1)].astype(jnp.float32)
+        local = ct_ref[pl.ds(s, 1), :]                      # (1, TO)
+        val = vt_ref[pl.ds(s, 1), :]
         sub_ref[...] += jnp.where(iota == local, val, 0.0)
         return carry
 
     jax.lax.fori_loop(0, n_slots, body, 0)
 
     x = x_ref[...].astype(jnp.float32)
-    acc_ref[...] += jax.lax.dot_general(
-        x, sub_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc_ref[...] += jnp.dot(x, sub_ref[...],
+                            preferred_element_type=jnp.float32)
 
     @pl.when(last)
     def _epilogue():
@@ -295,13 +297,15 @@ def _spmm_padded(x, vals, cols, bias, *, nm_aligned: bool, tile_t: int,
         out_specs=pl.BlockSpec((tile_t, tile_o), lambda t, o, s, d: (t, o)),
         out_shape=jax.ShapeDtypeStruct((Tp, Op), jnp.float32),
         scratch_shapes=[pltpu.VMEM((tile_t, tile_o), jnp.float32),
-                        pltpu.VMEM((tile_o, tile_d), jnp.float32)],
+                        pltpu.VMEM((tile_d, tile_o), jnp.float32),
+                        pltpu.VMEM((tile_s, tile_o), jnp.float32),
+                        pltpu.VMEM((tile_s, tile_o), jnp.int32)],
         interpret=interpret,
     )(x, vals, cols, bias)
     return out
 
 
-def _spmm_pallas(x2, vals, cols, d_in, plan, *, bias, act, interpret):
+def _spmm_pallas(x2, vals, cols, *, d_in, plan, bias, act, interpret):
     T, _ = x2.shape
     d_out, K = vals.shape
     tt, to = plan["tile_t"], plan["tile_o"]
@@ -319,6 +323,29 @@ def _spmm_pallas(x2, vals, cols, d_in, plan, *, bias, act, interpret):
                      tile_t=tt, tile_o=to, tile_d=plan["tile_d"],
                      tile_s=plan["tile_s"], act=act, interpret=interpret)
     return y[:T, :d_out]
+
+
+def _per_device_rows(run, x2, vals, cols, bias, mesh):
+    """Run the kernel whole, or on each device of ``mesh``.
+
+    XLA cannot partition a Mosaic kernel, so on a mesh each device runs
+    it inside a ``shard_map`` on its own slice of output rows (split over
+    every mesh axis, the placement ``dist.specs`` gives packed leaves),
+    with x replicated.
+    """
+    if mesh is None or mesh.size == 1:
+        return run(x2, vals, cols, bias=bias)
+    if vals.shape[0] % mesh.size:
+        raise ValueError(
+            f"spmm: {vals.shape[0]} output rows do not split over the "
+            f"{mesh.size} devices of the mesh")
+    rows = tuple(mesh.axis_names)
+    if bias is None:
+        bias = jnp.zeros(vals.shape[0], jnp.float32)
+    return jax.shard_map(
+        lambda x, v, c, b: run(x, v, c, bias=b), mesh=mesh,
+        in_specs=(P(), P(rows, None), P(rows, None), P(rows)),
+        out_specs=P(None, rows), check_vma=False)(x2, vals, cols, bias)
 
 
 # ---------------------------------------------------------------------------
@@ -403,7 +430,7 @@ def abs_columns(pw: PackedWeight) -> jnp.ndarray:
 def _dispatch(x, vals, cols, d_in: int, *, nm: tuple[int, int] | None,
               kernel: str, interpret: bool | None, tile_t: int,
               tile_o: int, tile_d: int, tile_s: int, bias=None,
-              act: str | None = None):
+              act: str | None = None, mesh=None):
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
     T = x2.shape[0]
@@ -420,17 +447,21 @@ def _dispatch(x, vals, cols, d_in: int, *, nm: tuple[int, int] | None,
         plan["nm_aligned"] = nm is not None
         est = _vmem_bytes(plan, x.dtype.itemsize, vals.dtype.itemsize)
         if est > _VMEM_BOUND:
-            # correctness first: serve through jnp — but never silently
-            _warn_vmem_fallback(d_in, (plan["tile_t"], plan["tile_o"],
-                                       plan["tile_d"]), est)
-            kernel, reason = "jnp", "vmem"
+            tiles = (plan["tile_t"], plan["tile_o"], plan["tile_d"],
+                     plan["tile_s"])
+            raise ValueError(
+                f"spmm: tiles (tile_t, tile_o, tile_d, tile_s)={tiles} "
+                f"need ~{est / 2**20:.1f} MiB VMEM per grid step for "
+                f"d_in={d_in} (bound {_VMEM_BOUND / 2**20:.0f} MiB); "
+                f"shrink the tiles")
     if kernel == "jnp":
         y = _spmm_jnp(x2, vals, cols, d_in, nm=nm, bias=bias, act=act)
     elif kernel == "pallas":
         if interpret is None:
             interpret = not _on_tpu()
-        y = _spmm_pallas(x2, vals, cols, d_in, plan, bias=bias, act=act,
-                         interpret=interpret)
+        run = functools.partial(_spmm_pallas, d_in=d_in, plan=plan, act=act,
+                                interpret=interpret)
+        y = _per_device_rows(run, x2, vals, cols, bias, mesh)
     else:
         raise ValueError(f"unknown spmm kernel {kernel!r}")
     _record(kernel, fmt, T, d_out, d_in, reason)
@@ -441,26 +472,29 @@ def spmm_nm24(x, values, idx, *, n: int = 2, m: int = 4,
               d_in: int | None = None, kernel: str = "auto",
               interpret: bool | None = None, tile_t: int = TILE_T,
               tile_o: int = TILE_O, tile_d: int = TILE_D,
-              bias=None, act: str | None = None):
+              bias=None, act: str | None = None, mesh=None):
     """x: (..., d_in) @ packed-N:M weightᵀ -> (..., d_out), epilogue fused.
 
     ``values``: (d_out, nb·n) kept weights; ``idx``: matching uint8
     within-block positions. ``bias`` ((d_out,) or None) and ``act`` (an
     ``EPILOGUES`` key or None) run inside the kernel on the fp32
-    accumulator.
+    accumulator. With a ``mesh`` the Pallas kernel runs on each device
+    over its slice of output rows.
     """
     if d_in is None:
         d_in = values.shape[-1] * m // n
     cols = _abs_columns_nm(idx, n, m)
     return _dispatch(x, values, cols, d_in, nm=(n, m), kernel=kernel,
                      interpret=interpret, tile_t=tile_t, tile_o=tile_o,
-                     tile_d=tile_d, tile_s=TILE_S, bias=bias, act=act)
+                     tile_d=tile_d, tile_s=TILE_S, bias=bias, act=act,
+                     mesh=mesh)
 
 
 def spmm_gather(x, values, idx, *, d_in: int, kernel: str = "auto",
                 interpret: bool | None = None, tile_t: int = TILE_T,
                 tile_o: int = TILE_O, tile_d: int = TILE_D,
-                tile_s: int = TILE_S, bias=None, act: str | None = None):
+                tile_s: int = TILE_S, bias=None, act: str | None = None,
+                mesh=None):
     """x: (..., d_in) @ gathered weightᵀ -> (..., d_out), epilogue fused.
 
     ``values``: (d_out, k) kept weights; ``idx``: int32 absolute kept
@@ -469,11 +503,12 @@ def spmm_gather(x, values, idx, *, d_in: int, kernel: str = "auto",
     return _dispatch(x, values, idx.astype(jnp.int32), d_in, nm=None,
                      kernel=kernel, interpret=interpret, tile_t=tile_t,
                      tile_o=tile_o, tile_d=tile_d, tile_s=tile_s,
-                     bias=bias, act=act)
+                     bias=bias, act=act, mesh=mesh)
 
 
 def spmm(x, pw: PackedWeight, *, kernel: str = "auto",
-         interpret: bool | None = None, bias=None, act: str | None = None):
+         interpret: bool | None = None, bias=None, act: str | None = None,
+         mesh=None):
     """Dispatch on a 2-D (d_out, k) ``PackedWeight`` leaf."""
     if pw.values.ndim != 2:
         raise ValueError(
@@ -482,14 +517,14 @@ def spmm(x, pw: PackedWeight, *, kernel: str = "auto",
     if pw.fmt == "nm24":
         return spmm_nm24(x, pw.values, pw.idx, n=pw.n, m=pw.m,
                          d_in=pw.d_in, kernel=kernel, interpret=interpret,
-                         bias=bias, act=act)
+                         bias=bias, act=act, mesh=mesh)
     return spmm_gather(x, pw.values, pw.idx, d_in=pw.d_in, kernel=kernel,
-                       interpret=interpret, bias=bias, act=act)
+                       interpret=interpret, bias=bias, act=act, mesh=mesh)
 
 
 def spmm_stacked(x, pw: PackedWeight, *, kernel: str = "auto",
                  interpret: bool | None = None, bias=None,
-                 act: str | None = None):
+                 act: str | None = None, mesh=None):
     """Per-instance spmm over one stacked leading dim (MoE experts).
 
     x: (N, ..., d_in); pw values/idx: (N, d_out, k) -> (N, ..., d_out).
@@ -498,6 +533,7 @@ def spmm_stacked(x, pw: PackedWeight, *, kernel: str = "auto",
 
     def one(xi, vi, ii):
         return spmm(xi, _dc.replace(pw, values=vi, idx=ii),
-                    kernel=kernel, interpret=interpret, bias=bias, act=act)
+                    kernel=kernel, interpret=interpret, bias=bias, act=act,
+                    mesh=mesh)
 
     return jax.vmap(one)(x, pw.values, pw.idx)

@@ -15,22 +15,30 @@ from __future__ import annotations
 import contextlib
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from repro.dist import sharding as sharding_lib
+
+
+def make_mesh(shape, names) -> Mesh:
+    """``jax.make_mesh`` with Auto axes. The logical-axis rules place
+    arrays with ``with_sharding_constraint``, which refuses the Explicit
+    axes ``jax.make_mesh`` builds by default."""
+    return jax.make_mesh(tuple(shape), tuple(names),
+                         axis_types=(AxisType.Auto,) * len(names))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(*, data: int | None = None, model: int = 1) -> Mesh:
     """Small mesh over whatever devices exist (tests / CPU runs)."""
     n = len(jax.devices())
     data = data if data is not None else n // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 @contextlib.contextmanager

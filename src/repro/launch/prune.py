@@ -40,6 +40,7 @@ import repro.configs as configs
 import repro.models as models
 from repro import ckpt, pruning
 from repro.core import masks as masks_lib
+from repro.launch import compile_cache
 from repro.train import steps as steps_lib
 
 # the one shared parser (core.masks); kept under its historical name
@@ -56,7 +57,8 @@ def _build_recipe(pattern, *, recipe: str | None, warmstart: str,
         t_max=t_max, k_swaps=k_swaps)
 
 
-def prune(arch: str, *, tiny: bool = True, pattern="0.6",
+def prune(arch: str, *, tiny: bool = True, n_layers: int | None = None,
+          pattern="0.6",
           warmstart: str = "wanda", method: str = "sparseswaps",
           t_max: int = 50, k_swaps: int | None = None,
           compact_every: int | None = None,
@@ -68,7 +70,10 @@ def prune(arch: str, *, tiny: bool = True, pattern="0.6",
           calib_stats: str = "full", recover: str | None = None,
           recover_steps: int = 50, recover_lr: float = 1e-3,
           verbose: bool = True) -> dict:
-    """``mesh``: None (single device), "host" (all local devices), or
+    """``n_layers`` cuts the config's depth and nothing else (weights
+    are random from ``seed``).
+
+    ``mesh``: None (single device), "host" (all local devices), or
     "production" — sparseswaps refinement then runs row-sharded via
     repro.dist (groups whose method has no distributed refiner are marked
     "single-device" in the plan).
@@ -79,10 +84,15 @@ def prune(arch: str, *, tiny: bool = True, pattern="0.6",
     recipe-attached ``recover`` spec. Recovered weights are evaluated,
     checkpointed under ``<out>/prune_ckpt/recover``, and their changed
     leaves dumped to ``<out>/weights`` — ``launch/serve.py --masks-from
-    <out>`` then serves the recovered model directly."""
+    <out>`` then serves the recovered model directly.
+
+    Returns the report, the dense and pruned evaluations, and the
+    ``executor`` (its calibration statistics stay reachable)."""
     import dataclasses as _dc
 
-    cfg = configs.get_tiny(arch) if tiny else configs.get(arch)
+    cfg = configs.resolve(arch, tiny=tiny, n_layers=n_layers)
+    if verbose:
+        print(configs.describe(cfg))
     api = models.build(cfg)
     rec = _build_recipe(pattern, recipe=recipe, warmstart=warmstart,
                         method=method, t_max=t_max, k_swaps=k_swaps)
@@ -197,7 +207,8 @@ def prune(arch: str, *, tiny: bool = True, pattern="0.6",
                 else None,
             }
         (out / "report.json").write_text(json.dumps(doc, indent=1))
-    out_d = {"report": report, "dense": dense_eval, "pruned": sparse_eval}
+    out_d = {"report": report, "dense": dense_eval, "pruned": sparse_eval,
+             "executor": executor}
     if recovered_eval is not None:
         out_d["recovered"] = recovered_eval
         out_d["recover_result"] = rec_res
@@ -208,6 +219,8 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--tiny", action="store_true")
+    ap.add_argument("--n-layers", type=int, default=None,
+                    help="cut the depth to N layers (widths unchanged)")
     ap.add_argument("--sparsity", default="0.6", help="fraction or N:M")
     ap.add_argument("--warmstart", default="wanda",
                     choices=["magnitude", "wanda", "ria"])
@@ -246,7 +259,9 @@ def main(argv=None):
     ap.add_argument("--recover-lr", type=float, default=1e-3,
                     help="recovery peak learning rate (warmup-cosine)")
     args = ap.parse_args(argv)
-    prune(args.arch, tiny=args.tiny, pattern=args.sparsity,
+    compile_cache.enable()
+    prune(args.arch, tiny=args.tiny, n_layers=args.n_layers,
+          pattern=args.sparsity,
           warmstart=args.warmstart, method=args.method, t_max=args.t_max,
           k_swaps=args.k_swaps, compact_every=args.compact_every,
           n_calib=args.n_calib, from_ckpt=args.from_ckpt,

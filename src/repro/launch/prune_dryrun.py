@@ -32,7 +32,6 @@ from pathlib import Path
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import masks as masks_lib
@@ -88,10 +87,10 @@ def _refine_fn(mesh, pattern, *, t_max: int, variant: str, chunk: int = 512,
 
         return step
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(w_spec, g_spec, w_spec),
              out_specs=(w_spec, P(axes), P(axes)),
-             check_rep=False)
+             check_vma=False)
     def run(w, g, m0):
         c0 = sm.correlation_vector(w, m0, g)
         l0 = sm.row_loss(w, m0, g)

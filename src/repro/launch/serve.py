@@ -34,13 +34,15 @@ import jax
 import repro.configs as configs
 import repro.models as models
 from repro.data import synthetic
+from repro.launch import compile_cache
 from repro.launch import mesh as mesh_lib
 from repro.serve import ServeEngine, bench_rows
 
 BENCH_OUT = Path(__file__).resolve().parents[3] / "BENCH_serve.json"
 
 
-def serve(arch: str, *, tiny: bool = True, batch: int = 4,
+def serve(arch: str, *, tiny: bool = True, n_layers: int | None = None,
+          batch: int = 4,
           prompt_len: int = 32, gen: int = 16, masks=None,
           masks_from: str | None = None, fmt: str | None = None,
           kernel: str = "auto", mesh: str | None = None, seed: int = 0,
@@ -54,7 +56,11 @@ def serve(arch: str, *, tiny: bool = True, batch: int = 4,
           disaggregate: bool = False, prefill_chunk: int | None = None,
           chaos: bool = False, chaos_seed: int = 0,
           verbose: bool = True) -> dict:
-    """Serve a batch of prompts; returns tokens + timing (+ bench rows).
+    """Serve a batch of prompts; returns tokens + timing (+ bench rows)
+    and the ``engine`` that served them.
+
+    ``n_layers`` cuts the config's depth and nothing else (weights are
+    random from ``seed``).
 
     ``masks``/``masks_from`` feed the sparse formats. ``fmt=None`` picks
     the faithful default — "masked" when a mask source is given, "dense"
@@ -79,7 +85,9 @@ def serve(arch: str, *, tiny: bool = True, batch: int = 4,
     exits nonzero if any fault path leaks pages or perturbs a
     completed token stream.
     """
-    cfg = configs.get_tiny(arch) if tiny else configs.get(arch)
+    cfg = configs.resolve(arch, tiny=tiny, n_layers=n_layers)
+    if verbose:
+        print(configs.describe(cfg))
     api = models.build(cfg)
     params = api.init(jax.random.key(seed))
     mesh_obj = None
@@ -87,9 +95,7 @@ def serve(arch: str, *, tiny: bool = True, batch: int = 4,
         mesh_obj = (mesh_lib.make_production_mesh() if mesh == "production"
                     else mesh_lib.make_host_mesh())
 
-    corpus = synthetic.CorpusConfig(cfg.vocab_size, seed=seed)
-    pipe = synthetic.DataPipeline(corpus, batch, prompt_len, split="val")
-    prompt = synthetic.with_modality(pipe.get(0), cfg, jax.random.key(seed))
+    prompt = make_prompt(cfg, batch, prompt_len, seed)
 
     mask_src = masks_from if masks_from is not None else masks
     if fmt is None:
@@ -109,7 +115,7 @@ def serve(arch: str, *, tiny: bool = True, batch: int = 4,
     res = engine.generate(prompt, gen, sampling=sample)
     out = {"tokens": res.tokens, "wall_s": res.prefill_s + res.decode_s,
            "tok_s": res.tok_s, "weight_bytes": engine.weight_bytes(),
-           "format": fmt}
+           "format": fmt, "engine": engine}
     if verbose:
         print(f"{arch}: served {batch} requests, {gen} new tokens each in "
               f"{out['wall_s']:.2f}s ({res.tok_s:.1f} decode tok/s, "
@@ -175,6 +181,10 @@ def serve(arch: str, *, tiny: bool = True, batch: int = 4,
         out["load_bench"] = load_rows
         if verbose:
             for r in load_rows:
+                if "error" in r:
+                    print(f"  {r['variant']:8s} {r['mode']:13s} rate "
+                          f"{r['arrival_rate']:5.1f}/s  ERROR {r['error']}")
+                    continue
                 print(f"  {r['variant']:8s} {r['mode']:13s} "
                       f"rate {r['arrival_rate']:5.1f}/s  goodput "
                       f"{r['goodput_tok_s']:8.1f} tok/s  p99 TTFT "
@@ -184,6 +194,12 @@ def serve(arch: str, *, tiny: bool = True, batch: int = 4,
                       f"{r['wasted_decode_tokens']:5d}  "
                       f"[{r['kernel_used']}]")
             print(f"wrote {path}")
+        errors = [r for r in load_rows if "error" in r]
+        if errors:
+            raise SystemExit(
+                f"{len(errors)} load cell(s) failed: " + "; ".join(
+                    f"{r['variant']}/{r['mode']}@{r['arrival_rate']}: "
+                    f"{r['error']}" for r in errors))
 
     if chaos:
         from repro.serve import FaultPlan, loadgen
@@ -211,6 +227,13 @@ def serve(arch: str, *, tiny: bool = True, batch: int = 4,
     return out
 
 
+def make_prompt(cfg, batch: int, prompt_len: int, seed: int) -> dict:
+    """The (batch, prompt_len) validation-split prompt ``serve`` runs."""
+    corpus = synthetic.CorpusConfig(cfg.vocab_size, seed=seed)
+    pipe = synthetic.DataPipeline(corpus, batch, prompt_len, split="val")
+    return synthetic.with_modality(pipe.get(0), cfg, jax.random.key(seed))
+
+
 def _servable(formats, api, params, mask_src) -> list:
     """Drop packed formats the mask source cannot represent (e.g. nm24
     for an unstructured per-row mask) instead of failing the bench.
@@ -226,6 +249,8 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--tiny", action="store_true")
+    ap.add_argument("--n-layers", type=int, default=None,
+                    help="cut the depth to N layers (widths unchanged)")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
@@ -288,9 +313,11 @@ def main(argv=None):
                     help="seed for FaultPlan.chaos")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
     from repro.serve.sampling import parse_sample_flag
     span = lambda s: tuple(int(x) for x in s.split(":", 1))
-    serve(args.arch, tiny=args.tiny, batch=args.batch,
+    serve(args.arch, tiny=args.tiny, n_layers=args.n_layers,
+          batch=args.batch,
           prompt_len=args.prompt_len, gen=args.gen,
           masks_from=args.masks_from, fmt=args.format, kernel=args.kernel,
           mesh=args.mesh, seed=args.seed, bench=args.bench,

@@ -185,6 +185,7 @@ class MatmulPolicy:
 
     kernel: str = "auto"
     fuse_epilogue: bool = True
+    mesh = None     # the mesh packed leaves are row-sharded on (or None)
 
     def matmul(self, x: jnp.ndarray, w: jnp.ndarray,
                mask: jnp.ndarray | None, *, bias=None,
@@ -196,7 +197,8 @@ class MatmulPolicy:
     def packed_matmul(self, x: jnp.ndarray, pw: PackedWeight, *,
                       bias=None, act: str | None = None) -> jnp.ndarray:
         from repro.kernels import spmm
-        return spmm.spmm(x, pw, kernel=self.kernel, bias=bias, act=act)
+        return spmm.spmm(x, pw, kernel=self.kernel, bias=bias, act=act,
+                         mesh=self.mesh)
 
     def packed_matmul_stacked(self, x: jnp.ndarray, pw: PackedWeight, *,
                               bias=None, act: str | None = None
@@ -204,15 +206,17 @@ class MatmulPolicy:
         """Per-instance variant for stacked leaves (MoE experts)."""
         from repro.kernels import spmm
         return spmm.spmm_stacked(x, pw, kernel=self.kernel, bias=bias,
-                                 act=act)
+                                 act=act, mesh=self.mesh)
 
 
 class PackedMatmulPolicy(MatmulPolicy):
     """A ``MatmulPolicy`` with an explicit kernel/epilogue choice."""
 
-    def __init__(self, kernel: str = "auto", fuse_epilogue: bool = True):
+    def __init__(self, kernel: str = "auto", fuse_epilogue: bool = True,
+                 mesh=None):
         self.kernel = kernel
         self.fuse_epilogue = fuse_epilogue
+        self.mesh = mesh
 
 
 DEFAULT_MATMUL_POLICY = MatmulPolicy()

@@ -33,7 +33,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import masks as masks_lib
@@ -64,10 +63,10 @@ def refine_rows_sharded(W, G, mask_init, pattern: masks_lib.Pattern, mesh,
     method = "pallas" if use_kernel else "chunked"
 
     @partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(axes, None), P(None, None), P(axes, None)),
         out_specs=(P(axes, None), P(axes), P(axes)),
-        check_rep=False,
+        check_vma=False,
     )
     def run(w, g, m0):
         c0 = sm.correlation_vector(w, m0, g)
@@ -127,11 +126,11 @@ def refine_g_sharded(W, G, mask_init, pattern: masks_lib.Pattern, mesh,
     row_spec = tuple(row_axes) if row_axes else None
 
     @partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(row_spec, None), P(None, axes), P(row_spec, None),
                   P(None)),
         out_specs=(P(row_spec, None), P(row_spec), P(row_spec)),
-        check_rep=False,
+        check_vma=False,
     )
     def run(w, g_cols, m0, g_diag):
         R = w.shape[0]
@@ -141,7 +140,8 @@ def refine_g_sharded(W, G, mask_init, pattern: masks_lib.Pattern, mesh,
         start = idx * cols
         # c = G @ wp  =>  c_own = G[own, :] @ wp; by symmetry
         # G[own, j] = G[j, own] = g_cols[j, :], so c_own = wp @ g_cols.
-        c_own0 = ((1.0 - m0) * w) @ g_cols                     # (R, cols)
+        c_own0 = jnp.matmul((1.0 - m0) * w, g_cols,
+                            precision=sm.HIGHEST)              # (R, cols)
         c_full0 = _gather_cols(c_own0, axes)                   # (R, d)
         l0 = jnp.sum(((1.0 - m0) * w) * c_full0, axis=1)
 

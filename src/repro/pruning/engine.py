@@ -151,7 +151,7 @@ def _row_loss_diag_batch(W, M, diag):
     objective.
     """
     C = W.astype(jnp.float32) * (1.0 - M)
-    return jnp.einsum("nrj,nj->nr", C * C, diag.astype(jnp.float32))
+    return jnp.sum(C * C * diag.astype(jnp.float32)[:, None, :], axis=-1)
 
 
 def _no_swaps(W):
@@ -250,9 +250,7 @@ def _refine_sparsegpt(W, gram, pattern, ctx):
     # loss of the (mask + updated weights) pair w.r.t. the dense output:
     # ||WX - W1X||^2 via G
     diff = W.astype(jnp.float32) - W1
-    l1 = jax.vmap(
-        lambda dd, g: jnp.einsum("ri,ij,rj->r", dd, g.astype(jnp.float32), dd)
-    )(diff, gram.G)
+    l1 = jax.vmap(sm.quad_form)(diff, gram.G)
     return GroupResult(masks=m1, loss_init=l0, loss_final=l1,
                        swaps=_no_swaps(W), new_weights=W1)
 
@@ -376,7 +374,7 @@ def refine_instance(W, gram: sites_lib.GramStats, pattern, *, method: str,
     if method == "sparsegpt":
         W1, m1 = _sparsegpt(W, G, pattern)
         diff = (W.astype(jnp.float32) - W1)
-        l1 = jnp.einsum("ri,ij,rj->r", diff, G.astype(jnp.float32), diff)
+        l1 = sm.quad_form(diff, G)
         return m1, l0, l1, jnp.zeros(W.shape[0], jnp.int32), W1
 
     raise ValueError(f"unknown method {method!r}")

@@ -8,6 +8,8 @@ multiple-choice harness tasks).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -31,8 +33,10 @@ def perplexity(api: ModelApi, params, batches, *, masks=None) -> float:
 
 
 def make_acc_step(api: ModelApi, *, masks=None):
+    """jit'd (params, batch) -> (top-1 hits, valid tokens); ``masks`` go
+    in as an argument, not a closure constant (``steps.make_eval_step``)."""
     @jax.jit
-    def step(params, batch):
+    def step(params, batch, masks):
         hidden, _, _ = api.forward(params, batch, masks=masks)
         logits = api.module.lm_head(params, hidden, api.cfg)
         pred = jnp.argmax(logits, axis=-1)
@@ -40,7 +44,7 @@ def make_acc_step(api: ModelApi, *, masks=None):
         hit = (pred == batch["labels"]) & valid
         return jnp.sum(hit), jnp.sum(valid)
 
-    return step
+    return functools.partial(step, masks=masks)
 
 
 def top1_accuracy(api: ModelApi, params, batches, *, masks=None) -> float:

@@ -47,7 +47,6 @@ from pathlib import Path
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import ckpt
@@ -332,8 +331,8 @@ def make_sharded_step(api: ModelApi, spec: CalibSpec, mesh: Mesh,
 
         return _map_entries(aux["taps"], merge)
 
-    local = shard_map(local, mesh=mesh, in_specs=(P(), batch_specs),
-                      out_specs=P(), check_rep=False)
+    local = jax.shard_map(local, mesh=mesh, in_specs=(P(), batch_specs),
+                          out_specs=P(), check_vma=False)
 
     @partial(jax.jit, donate_argnums=(1,), out_shardings=state_shardings)
     def step(params, state, batch):

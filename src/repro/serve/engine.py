@@ -24,8 +24,8 @@ run under interpret off-TPU when forced).
 ``bench_rows`` emits the ``BENCH_serve.json`` rows the launcher writes:
 separate prefill and decode rows per format (dense vs masked-dense vs
 packed), each tagged with the kernel the trace actually lowered
-(``kernel_used``) so jnp/VMEM fallbacks show up in the perf trajectory
-instead of hiding inside an aggregate tok/s.
+(``kernel_used``) so a jnp path shows up in the perf trajectory instead
+of hiding inside an aggregate tok/s.
 """
 from __future__ import annotations
 
@@ -120,12 +120,12 @@ class ServeEngine:
             self.params = params
             self.masks = masks if fmt == "masked" else None
         self.pack_s = time.time() - t0
-        self._policy = common.PackedMatmulPolicy(kernel)
+        self._policy = common.PackedMatmulPolicy(kernel, mesh=mesh)
         self._steps = None              # (prefill, decode) jits, built once
         self._scans: dict = {}          # (n_steps, want_logits, sampled) -> jit
         self._fns: dict = {}            # scheduler-facing compiled fns
         # per-phase kernel actually lowered at trace time ("dense" for the
-        # unpacked formats, else e.g. "jnp" / "pallas" / "jnp(vmem)")
+        # unpacked formats, else "jnp" / "pallas")
         self.kernel_used: dict = {}
         # fault-injection seam: called as hook(phase) inside the timed
         # dispatch region of every scheduler-facing entry point, so an
@@ -203,12 +203,12 @@ class ServeEngine:
         """
         key = (n_steps, want_logits, sampled)
         if key not in self._scans:
-            _, decode = self._serve_steps()
-
-            def run(params, tok0, cache, samp):
+            api = self.api      # the jit closes over api, never self
+            def run(params, masks, tok0, cache, samp):
                 def step(carry, _):
                     tok, cache = carry
-                    logits, cache = decode(params, tok[:, None], cache)
+                    logits, cache = api.decode_step(
+                        params, tok[:, None], cache, masks=masks)
                     if sampled:
                         # post-step cache.t IS the absolute position of
                         # the token being sampled (the PRNG key index)
@@ -287,7 +287,7 @@ class ServeEngine:
                 run = self._decode_scan(n_new - 1, want_logits,
                                         samp is not None)
                 with spmm.record_dispatch() as rec_d:
-                    ys = run(self.params, tok0, cache, samp)
+                    ys = run(self.params, self.masks, tok0, cache, samp)
                 toks, logit_steps = ys if want_logits else (ys, None)
                 out = jnp.concatenate([tok0[:, None], toks.T], axis=1)
             else:
@@ -360,11 +360,12 @@ class ServeEngine:
         s_bucket = tokens.shape[1]
         key = ("prefill_session", s_bucket)
         if key not in self._fns:
-            def fn(params, tokens, n_valid, samp):
-                cache = self.api.init_cache(params, 1, s_bucket)
-                logits, cache = self.api.prefill(
+            api = self.api      # the jit closes over api, never self
+            def fn(params, masks, tokens, n_valid, samp):
+                cache = api.init_cache(params, 1, s_bucket)
+                logits, cache = api.prefill(
                     params, {"tokens": tokens, "n_valid": n_valid}, cache,
-                    masks=self.masks)
+                    masks=masks)
                 tok0 = sampling_lib.sample_tokens(
                     logits[:, -1], samp["temp"], samp["top_p"],
                     samp["top_k"], samp["seed"], n_valid)
@@ -376,7 +377,7 @@ class ServeEngine:
             if self.dispatch_hook is not None:
                 self.dispatch_hook("prefill")
             with spmm.record_dispatch() as rec:
-                out = self._fns[key](self.params, tokens,
+                out = self._fns[key](self.params, self.masks, tokens,
                                      jnp.int32(n_valid), samp)
             jax.block_until_ready(out[0])
         self._note_kernels("prefill", rec)
@@ -411,22 +412,23 @@ class ServeEngine:
         capacity = cache.kv.k.shape[2]
         key = ("prefill_chunk", w, capacity)
         if key not in self._fns:
-            def fn(params, tokens, offset, n_valid, cache, samp):
-                logits, cache = self.api.prefill_window(
+            api = self.api      # the jit closes over api, never self
+            def fn(params, masks, tokens, offset, n_valid, cache, samp):
+                logits, cache = api.prefill_window(
                     params, {"tokens": tokens, "offset": offset,
-                             "n_valid": n_valid}, cache, masks=self.masks)
+                             "n_valid": n_valid}, cache, masks=masks)
                 tok0 = sampling_lib.sample_tokens(
                     logits[:, -1], samp["temp"], samp["top_p"],
                     samp["top_k"], samp["seed"], n_valid)
                 return tok0, cache
 
-            self._fns[key] = jax.jit(fn, donate_argnums=4)
+            self._fns[key] = jax.jit(fn, donate_argnums=5)
         with self._ctx(), common.use_matmul_policy(self._policy):
             if self.dispatch_hook is not None:
                 self.dispatch_hook("prefill")
             with spmm.record_dispatch() as rec:
                 tok0, cache = self._fns[key](
-                    self.params, tokens, jnp.int32(offset),
+                    self.params, self.masks, tokens, jnp.int32(offset),
                     jnp.int32(n_valid), cache, samp)
             jax.block_until_ready(tok0)
         self._note_kernels("prefill", rec)
@@ -453,7 +455,8 @@ class ServeEngine:
         from repro.models.transformer import DecodeCache
         key = ("chunk", n_steps, bucket)
         if key not in self._fns:
-            def fn(params, tok, cache, active, samp):
+            api = self.api      # the jit closes over api, never self
+            def fn(params, masks, tok, cache, active, samp):
                 kv = cache.kv
                 sub = DecodeCache(
                     kv=attn.KVCache(kv.k[:, :bucket], kv.v[:, :bucket],
@@ -463,8 +466,8 @@ class ServeEngine:
 
                 def step(carry, _):
                     tk, c = carry
-                    logits, c2 = self.api.decode_step(
-                        params, tk[:, None], c, masks=self.masks)
+                    logits, c2 = api.decode_step(
+                        params, tk[:, None], c, masks=masks)
                     nxt = sampling_lib.sample_tokens(
                         logits[:, -1], samp["temp"][:bucket],
                         samp["top_p"][:bucket], samp["top_k"][:bucket],
@@ -484,13 +487,13 @@ class ServeEngine:
                     kv=kv_out, cross_kv=None,
                     t=cache.t.at[:bucket].set(sub.t))
 
-            self._fns[key] = jax.jit(fn, donate_argnums=2)
+            self._fns[key] = jax.jit(fn, donate_argnums=3)
         with self._ctx(), common.use_matmul_policy(self._policy):
             if self.dispatch_hook is not None:
                 self.dispatch_hook("decode")
             with spmm.record_dispatch() as rec:
-                toks, cache = self._fns[key](self.params, tok, cache,
-                                             active, samp)
+                toks, cache = self._fns[key](self.params, self.masks, tok,
+                                             cache, active, samp)
             jax.block_until_ready(toks)
         self._note_kernels("decode", rec)
         return toks, cache
@@ -502,11 +505,7 @@ class ServeEngine:
 
 def kernel_summary(rec: list) -> str:
     """Collapse trace-time dispatch records into one bench-row tag."""
-    names = sorted({r["kernel"] for r in rec})
-    tag = "+".join(names)
-    if any(r["reason"] == "vmem" for r in rec):
-        tag += "(vmem-fallback)"
-    return tag
+    return "+".join(sorted({r["kernel"] for r in rec}))
 
 
 _kernel_summary = kernel_summary
@@ -522,7 +521,7 @@ def bench_rows(api: ModelApi, params: dict, masks, prompt: dict,
     ``phase == "decode"`` — so the prefill gap is tracked directly
     instead of inferred from aggregate tok/s. Shared keys: ``variant``,
     ``kernel`` (requested), ``kernel_used`` (what the trace actually
-    lowered, per phase — fallbacks are visible here), ``tok_s`` (best
+    lowered, per phase — a jnp path is visible here), ``tok_s`` (best
     warm repeat), ``weight_bytes``, ``pack_s``. Prefill rows add
     ``prefill_s`` (best warm, tok_s = batch · prompt_len / prefill_s);
     decode rows add ``cold_tok_s`` (first call, pays compilation).

@@ -103,14 +103,20 @@ def decode_step_fn(api: ModelApi, *, masks=None):
 
 
 def make_eval_step(api: ModelApi, *, masks=None):
-    """jit'd (params, batch) -> (mean CE, valid-token count)."""
+    """jit'd (params, batch) -> (mean CE, valid-token count).
 
-    def step(params, batch):
+    ``masks`` enter the program as an argument: a closed-over array is
+    baked into the executable as a constant, which for a model's masks
+    means gigabytes of constants to fold at compile time.
+    """
+
+    @jax.jit
+    def step(params, batch, masks):
         loss, aux = api.loss(params, batch, masks=masks)
         n_valid = jnp.sum((batch["labels"] >= 0).astype(jnp.float32))
         return aux["ce"], n_valid
 
-    return jax.jit(step)
+    return partial(step, masks=masks)
 
 
 def perplexity(api: ModelApi, params, batches, *, masks=None) -> float:
@@ -131,9 +137,12 @@ def perplexity(api: ModelApi, params, batches, *, masks=None) -> float:
 
 
 def make_serve_steps(api: ModelApi, *, masks=None):
-    prefill = jax.jit(lambda p, b, c: api.prefill(p, b, c, masks=masks))
-    decode = jax.jit(lambda p, t, c: api.decode_step(p, t, c, masks=masks))
-    return prefill, decode
+    """jit'd prefill (params, batch, cache) and decode (params, token,
+    cache); ``masks`` are bound as an argument, never a closure constant
+    (see ``make_eval_step``)."""
+    prefill = jax.jit(lambda p, b, c, m: api.prefill(p, b, c, masks=m))
+    decode = jax.jit(lambda p, t, c, m: api.decode_step(p, t, c, masks=m))
+    return partial(prefill, m=masks), partial(decode, m=masks)
 
 
 def greedy_decode(api: ModelApi, params, prompt, n_new: int, *, masks=None):

@@ -102,7 +102,8 @@ def test_elastic_restore_onto_sharding(tmp_path, tree):
     """Restore with explicit shardings (device_put path)."""
     ckpt.save(tmp_path, 1, tree)
     from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.launch import mesh as mesh_lib
+    mesh = mesh_lib.make_mesh((1,), ("data",))
     sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), tree)
     got, _ = ckpt.restore(tmp_path, 1, jax.eval_shape(lambda: tree),
                           shardings=sh)

@@ -1,4 +1,6 @@
 """Data pipeline statistics, training convergence, serving consistency."""
+from pathlib import Path
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -112,3 +114,44 @@ def test_prune_launcher_smoke(tmp_path):
                 verbose=False)
     assert out["report"].mean_error_reduction() > 0
     assert (tmp_path / "report.json").exists()
+
+
+def test_launchers_cut_depth_only(capsys):
+    """``n_layers`` replaces the depth and nothing else, and the run
+    prints it next to the published depth."""
+    import repro.configs as configs
+    from repro.launch.prune import prune
+    from repro.launch.serve import serve
+    tiny = configs.get_tiny("minitron-4b")
+    cut = configs.resolve("minitron-4b", tiny=True, n_layers=1)
+    assert cut == tiny.replace(n_layers=1)
+    assert configs.resolve("minitron-4b", n_layers=4) == \
+        configs.get("minitron-4b").replace(n_layers=4)
+    out = prune("minitron-4b", tiny=True, n_layers=1, t_max=2, n_calib=2,
+                calib_seq=16, calib_batch=2)
+    assert all(len(s.labels) == 1 for s in out["report"].sites)
+    assert "n_layers=1 (published 32)" in capsys.readouterr().out
+    out = serve("minitron-4b", tiny=True, n_layers=1, batch=2,
+                prompt_len=8, gen=2)
+    assert out["engine"].cfg == cut
+    assert out["tokens"].shape == (2, 2)
+    assert "n_layers=1 (published 32)" in capsys.readouterr().out
+
+
+def test_compile_cache_dir(monkeypatch):
+    """The env variable wins and nothing is set; otherwise the cache sits
+    at a fixed path inside the checkout."""
+    import jax
+    from repro.launch import compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+    assert compile_cache.enable() == "/elsewhere"
+    assert jax.config.jax_compilation_cache_dir == prev
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        path = compile_cache.enable()
+        assert jax.config.jax_compilation_cache_dir == path
+        root = Path(__file__).resolve().parents[1]
+        assert path == str(root / ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

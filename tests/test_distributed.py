@@ -24,6 +24,7 @@ def run_py(code: str, devices: int = 8) -> str:
 def test_rows_sharded_matches_reference():
     out = run_py("""
         import numpy as np, jax, jax.numpy as jnp
+        from repro.launch import mesh as mesh_lib
         from repro.core import masks, warmstart, sparseswaps
         from repro.pruning import distributed as dist
         rng = np.random.default_rng(0)
@@ -32,7 +33,7 @@ def test_rows_sharded_matches_reference():
         G = jnp.asarray(X @ X.T)
         pat = masks.PerRow(0.5)
         m0 = warmstart.warmstart_mask(jnp.asarray(W), G, pat, "wanda")
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = mesh_lib.make_mesh((8,), ("data",))
         ref = sparseswaps.refine(jnp.asarray(W), G, m0, pat, t_max=15,
                                  method="chunked")
         m1, l0, l1 = dist.refine_rows_sharded(jnp.asarray(W), G, m0, pat,
@@ -45,6 +46,7 @@ def test_rows_sharded_matches_reference():
 def test_g_sharded_matches_reference():
     out = run_py("""
         import numpy as np, jax, jax.numpy as jnp
+        from repro.launch import mesh as mesh_lib
         from repro.core import masks, warmstart, sparseswaps
         from repro.pruning import distributed as dist
         rng = np.random.default_rng(1)
@@ -56,7 +58,7 @@ def test_g_sharded_matches_reference():
         ref = sparseswaps.refine(jnp.asarray(W), G, m0, pat, t_max=12,
                                  method="chunked")
         for shape, names in [((8,), ("data",)), ((4, 2), ("data", "model"))]:
-            mesh = jax.make_mesh(shape, names)
+            mesh = mesh_lib.make_mesh(shape, names)
             m2, _, _ = dist.refine_g_sharded(jnp.asarray(W), G, m0, pat,
                                              mesh, t_max=12)
             print("MATCH", shape, bool(jnp.all(m2 == ref.mask)))
@@ -69,6 +71,7 @@ def test_rows_sharded_kswap_matches_reference():
     over rows is bit-identical to the single-device k-swap loop."""
     out = run_py("""
         import numpy as np, jax, jax.numpy as jnp
+        from repro.launch import mesh as mesh_lib
         from repro.core import masks, warmstart, sparseswaps
         from repro.pruning import distributed as dist
         rng = np.random.default_rng(0)
@@ -77,7 +80,7 @@ def test_rows_sharded_kswap_matches_reference():
         G = jnp.asarray(X @ X.T)
         pat = masks.PerRow(0.5)
         m0 = warmstart.warmstart_mask(jnp.asarray(W), G, pat, "wanda")
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = mesh_lib.make_mesh((8,), ("data",))
         ref = sparseswaps.refine(jnp.asarray(W), G, m0, pat, t_max=15,
                                  method="chunked", k_swaps=8)
         m1, l0, l1 = dist.refine_rows_sharded(jnp.asarray(W), G, m0, pat,
@@ -93,6 +96,7 @@ def test_g_sharded_kswap_matches_reference():
     meshes, at k = 1 and k = 8."""
     out = run_py("""
         import numpy as np, jax, jax.numpy as jnp
+        from repro.launch import mesh as mesh_lib
         from repro.core import masks, warmstart, sparseswaps
         from repro.pruning import distributed as dist
         rng = np.random.default_rng(1)
@@ -106,7 +110,7 @@ def test_g_sharded_kswap_matches_reference():
                                      method="chunked", k_swaps=k)
             for shape, names in [((8,), ("data",)),
                                  ((4, 2), ("data", "model"))]:
-                mesh = jax.make_mesh(shape, names)
+                mesh = mesh_lib.make_mesh(shape, names)
                 m2, _, _ = dist.refine_g_sharded(jnp.asarray(W), G, m0, pat,
                                                  mesh, t_max=12, k_swaps=k)
                 print("MATCH", k, shape, bool(jnp.all(m2 == ref.mask)))
@@ -117,6 +121,7 @@ def test_g_sharded_kswap_matches_reference():
 def test_nm_rows_sharded():
     out = run_py("""
         import numpy as np, jax, jax.numpy as jnp
+        from repro.launch import mesh as mesh_lib
         from repro.core import masks, warmstart, sparseswaps
         from repro.pruning import distributed as dist
         rng = np.random.default_rng(2)
@@ -125,7 +130,7 @@ def test_nm_rows_sharded():
         G = jnp.asarray(X @ X.T)
         pat = masks.NM(2, 4)
         m0 = warmstart.warmstart_mask(jnp.asarray(W), G, pat, "wanda")
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = mesh_lib.make_mesh((8,), ("data",))
         ref = sparseswaps.refine(jnp.asarray(W), G, m0, pat, t_max=10)
         m1, _, _ = dist.refine_rows_sharded(jnp.asarray(W), G, m0, pat, mesh,
                                             t_max=10)
@@ -195,14 +200,14 @@ def test_data_parallel_gram_psum():
     """Gram accumulated per-shard + psum == global Gram."""
     out = run_py("""
         import numpy as np, jax, jax.numpy as jnp
+        from repro.launch import mesh as mesh_lib
         from functools import partial
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.core import gram as gram_lib
         rng = np.random.default_rng(3)
         acts = rng.normal(size=(8, 16, 12)).astype(np.float32)
-        mesh = jax.make_mesh((8,), ("data",))
-        @partial(shard_map, mesh=mesh, in_specs=P("data", None, None),
+        mesh = mesh_lib.make_mesh((8,), ("data",))
+        @partial(jax.shard_map, mesh=mesh, in_specs=P("data", None, None),
                  out_specs=P())
         def sharded_gram(a):
             st = gram_lib.GramState.create(12).update(a)
@@ -219,14 +224,15 @@ def test_elastic_checkpoint_reshard():
     """Save sharded on 8 devices -> restore onto 4-device mesh (and back)."""
     out = run_py("""
         import tempfile, numpy as np, jax, jax.numpy as jnp
+        from repro.launch import mesh as mesh_lib
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro import ckpt
-        mesh8 = jax.make_mesh((8,), ("data",))
+        mesh8 = mesh_lib.make_mesh((8,), ("data",))
         w = jnp.arange(64.0).reshape(8, 8)
         w = jax.device_put(w, NamedSharding(mesh8, P("data", None)))
         d = tempfile.mkdtemp()
         ckpt.save(d, 1, {"w": w})
-        mesh4 = jax.make_mesh((4, 2), ("data", "model"))
+        mesh4 = mesh_lib.make_mesh((4, 2), ("data", "model"))
         sh = {"w": NamedSharding(mesh4, P("model", "data"))}
         got, _ = ckpt.restore(d, 1, {"w": jax.ShapeDtypeStruct((8, 8),
                                                               jnp.float32)},
@@ -247,7 +253,7 @@ def test_train_step_sharded_runs():
         from repro.data import synthetic
         cfg = C.get_tiny("llama31-8b")
         api = M.build(cfg)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = mesh_lib.make_mesh((4, 2), ("data", "model"))
         with mesh_lib.activate(mesh, cfg):
             st = steps.init_state(api, jax.random.key(0))
             ts = steps.make_train_step(api, adamw.AdamWConfig(lr=1e-3))

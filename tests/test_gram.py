@@ -3,10 +3,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                       # container without hypothesis
-    from _hyposhim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from conftest import make_problem
 from repro.core import gram as gram_lib
@@ -63,7 +60,7 @@ def test_psum_gram_merges_hosts(rng):
                                rtol=1e-4, atol=1e-4)
 
 
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(n_dev=st.integers(2, 8), seed=st.integers(0, 10**6),
        d=st.sampled_from([4, 8, 13]))
 def test_psum_gram_uneven_splits(n_dev, seed, d):

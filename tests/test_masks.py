@@ -1,10 +1,7 @@
 """Sparsity patterns and top-k mask construction."""
 import numpy as np
 import jax.numpy as jnp
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                       # container without hypothesis
-    from _hyposhim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import masks as masks_lib
 

@@ -11,6 +11,8 @@ harness's leak/bitwise gates hold end to end. Plus the bench sweep's
 error-row tolerance and the checker's handling of it.
 """
 import importlib.util
+import json
+import re
 from pathlib import Path
 
 import numpy as np
@@ -461,6 +463,29 @@ def test_bench_sweep_survives_failing_cell(tiny, monkeypatch):
     errs = mod.check(doc, max_nm24_prefill_ratio=50.0,
                      require_continuous_wins=True)
     assert any("need both" in e for e in errs)
+
+
+def test_serve_load_bench_exits_nonzero_on_error_row(tmp_path, monkeypatch,
+                                                    capsys):
+    """The launcher writes and prints the sweep, error rows included,
+    then exits non-zero: a cell that failed must not pass as a finished
+    run."""
+    from repro.launch.serve import serve
+
+    def boom(*a, **kw):
+        raise RuntimeError("injected bench failure")
+
+    monkeypatch.setattr(loadgen, "run_fixed", boom)
+    out = tmp_path / "bench.json"
+    with pytest.raises(SystemExit, match="injected bench failure"):
+        serve("llama31-8b", batch=2, prompt_len=8, gen=2, load_bench=True,
+              load_rates=(32.0,), load_duration=0.2,
+              load_prompt_len=(4, 8), load_output_len=(2, 4),
+              bench_out=out, verbose=True)
+    rows = json.loads(out.read_text())["rows"]
+    assert [r["mode"] for r in rows if "error" in r] == ["fixed"]
+    assert re.search(r"fixed +rate .* ERROR .*injected bench failure",
+                     capsys.readouterr().out)
 
 
 def test_run_continuous_deadline_expires_on_virtual_clock(tiny):

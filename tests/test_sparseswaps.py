@@ -2,10 +2,7 @@
 import numpy as np
 import jax.numpy as jnp
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                       # container without hypothesis
-    from _hyposhim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from conftest import make_problem
 from repro.core import masks as masks_lib
