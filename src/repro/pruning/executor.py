@@ -20,11 +20,12 @@ verified bit-identical in tests.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import hashlib
 import json
-import time
 import warnings
+from functools import partial
 from pathlib import Path
 
 import jax
@@ -33,7 +34,9 @@ import numpy as np
 
 from repro import ckpt
 from repro.core import masks as masks_lib
+from repro.core import sparseswaps
 from repro.runtime import fault_tolerance as ft
+from repro.runtime import trace
 from repro.models import ModelApi
 
 from . import engine as engine_lib
@@ -65,7 +68,7 @@ class PruneReport:
     method: str                          # run-level; "mixed" if per-site
     warmstart: str
     pattern: str
-    wall_time_s: float
+    wall_time_s: float                   # prune.run span, masks ready
     updated_params: dict | None = None   # sparsegpt only
     plan: plan_lib.PrunePlan | None = None
 
@@ -74,11 +77,12 @@ class PruneReport:
         if not self.sites:            # e.g. an all-skip recipe
             return 0.0
         vals = jnp.concatenate([s.error_reduction for s in self.sites])
-        return float(jnp.mean(vals))
+        return float(trace.wait(jnp.mean(vals), "prune.report", np.asarray))
 
     def total_loss(self, which: str = "final") -> float:
         key = {"init": "loss_init", "final": "loss_final"}[which]
-        return float(sum(jnp.sum(getattr(s, key)) for s in self.sites))
+        total = sum(jnp.sum(getattr(s, key)) for s in self.sites)
+        return float(trace.wait(total, "prune.report", np.asarray))
 
     def summary(self) -> str:
         lines = [f"method={self.method} warmstart={self.warmstart} "
@@ -86,7 +90,8 @@ class PruneReport:
                  f"mean error reduction: {100*self.mean_error_reduction():.2f}%"]
         mixed = self.method == "mixed" or self.pattern == "mixed"
         for s in self.sites:
-            red = 100 * float(jnp.mean(s.error_reduction))
+            red = 100 * float(trace.wait(jnp.mean(s.error_reduction),
+                                         "prune.report", np.asarray))
             tag = f"  [{s.pattern} {s.method}]" if mixed else ""
             lines.append(f"  {s.name:28s} n={len(s.labels):3d} "
                          f"err-reduction {red:6.2f}%{tag}")
@@ -119,7 +124,8 @@ class PrintProgress(PruneCallback):
     """The old ``progress=True`` console lines, as a callback."""
 
     def on_group_done(self, planned, report, *, restored):
-        red = 100 * float(jnp.mean(report.error_reduction))
+        red = 100 * float(trace.wait(jnp.mean(report.error_reduction),
+                                     "prune.progress", np.asarray))
         tag = " (restored)" if restored else ""
         print(f"  {report.name:28s} err-reduction {red:6.2f}%{tag}")
 
@@ -159,8 +165,9 @@ def _data_fingerprint(g: sites_lib.SiteGroup) -> str:
     h = hashlib.sha256()
     stats = ((g.gram.G,) if g.gram.G is not None
              else (g.gram.gram_diag, g.gram.mean))
-    for arr in (g.weights, *stats):
-        h.update(np.ascontiguousarray(np.asarray(arr)).tobytes())
+    for arr in trace.wait((g.weights, *stats), "prune.fingerprint",
+                          jax.device_get):
+        h.update(np.ascontiguousarray(arr).tobytes())
     return h.hexdigest()
 
 
@@ -307,86 +314,83 @@ class PruneExecutor:
     # -- execution ----------------------------------------------------------
 
     def run(self, calib_batches=None) -> PruneReport:
-        """Execute the plan: calibrate -> refine per group -> apply."""
-        t_start = time.time()
+        """Execute the plan: calibrate -> refine per group -> apply.
+
+        Spans (``runtime.trace``): ``prune.run`` around
+        ``prune.calibrate``, ``prune.sites``, one ``prune.group`` per
+        active group and ``prune.assemble``. A group span holds
+        ``prune.refine`` and ``prune.check.wait`` and carries ``name``,
+        ``instances``, ``rows`` and ``d_in``; for a refined group, while
+        spans are recorded, also ``passes`` and ``rows_scored`` (the
+        search-pass count, ``core.sparseswaps.count_search_passes``),
+        ``swaps`` (committed swaps) and, for sparseswaps, ``k`` (the
+        candidate swaps per row and pass). ``wall_time_s`` is the
+        ``prune.run`` span, closed once the masks are ready.
+        """
         plan = self.plan
-        self.callback.on_plan(plan)
+        with trace.timed("prune.run") as run_span:
+            self.callback.on_plan(plan)
+            single = plan.single_device_groups()
+            if single:
+                # exactly once per run — the plan's describe() already
+                # marked these groups "single-device" before execution
+                warnings.warn(
+                    f"mesh= is only honored by method='sparseswaps'; "
+                    f"{len(single)} group(s) refine single-device: "
+                    + ", ".join(single))
 
-        single = plan.single_device_groups()
-        if single:
-            # exactly once per run — the plan's describe() already marked
-            # these groups "single-device" before execution started
-            warnings.warn(
-                f"mesh= is only honored by method='sparseswaps'; "
-                f"{len(single)} group(s) refine single-device: "
-                + ", ".join(single))
+            if self.taps is None:
+                if calib_batches is None:
+                    raise ValueError("no taps and no calib_batches to "
+                                     "accumulate them from")
+                with trace.span("prune.calibrate"):
+                    self._calibrate(calib_batches)
+            active = [pg for pg in plan.groups if not pg.skip]
+            # skip-listed groups never materialize their stacked
+            # weights/Grams
+            with trace.span("prune.sites"):
+                groups = {g.name: g for g in sites_lib.enumerate_sites(
+                    self.api.cfg, self.params, self.taps,
+                    only={pg.name for pg in active})}
 
-        if self.taps is None:
-            if calib_batches is None:
-                raise ValueError("no taps and no calib_batches to "
-                                 "accumulate them from")
-            # streaming, skip-aware, donated-carry accumulation; batches
-            # shard over the plan's mesh when they divide its data axes
-            spec = (self.calib_spec if self.calib_spec is not None
-                    else plan.calib_spec(minimal=False))
-            self.stats = stats_lib.accumulate_stats(
-                self.api, self.params, calib_batches, spec=spec,
-                mesh=plan.mesh,
-                ckpt_dir=(self.ckpt_dir / "calib"
-                          if self.ckpt_dir is not None else None),
-                checkpoint_every=self.calib_ckpt_every)
-            self.taps = self.stats.taps
-        active = [pg for pg in plan.groups if not pg.skip]
-        # skip-listed groups never materialize their stacked weights/Grams
-        groups = {g.name: g for g in sites_lib.enumerate_sites(
-            self.api.cfg, self.params, self.taps,
-            only={pg.name for pg in active})}
+            new_params = None
+            if any(pg.rule.method == "sparsegpt" for pg in active):
+                new_params = jax.tree.map(lambda x: x, self.params)
 
-        run_fn = {"batched": engine_lib.refine_group,
-                  "reference": engine_lib.refine_group_reference}[
-                      self.engine_mode]
-        new_params = None
-        if any(pg.rule.method == "sparsegpt" for pg in active):
-            new_params = jax.tree.map(lambda x: x, self.params)
+            site_masks: dict[str, jnp.ndarray] = {}
+            reports: list[SiteReport] = []
+            for i, pg in enumerate(active):
+                g = groups[pg.name]
+                self.callback.on_group_start(pg, i, len(active))
+                N, R, d = g.weights.shape
+                with trace.span("prune.group", name=pg.name, instances=N,
+                                rows=R, d_in=d) as span:
+                    res, restored = self._group(pg, g, i, span)
+                    site_masks[g.name] = res.masks
+                    rep = SiteReport(
+                        name=g.name, labels=g.labels(),
+                        loss_init=jnp.sum(res.loss_init, axis=1),
+                        loss_final=jnp.sum(res.loss_final, axis=1),
+                        swaps=jnp.sum(res.swaps, axis=1),
+                        pattern=pg.rule.pattern_str, method=pg.rule.method)
+                    reports.append(rep)
+                    if res.new_weights is not None:
+                        _write_updated_weights(new_params, g,
+                                               res.new_weights)
+                self.callback.on_group_done(pg, rep, restored=restored)
 
-        site_masks: dict[str, jnp.ndarray] = {}
-        reports: list[SiteReport] = []
-        for i, pg in enumerate(active):
-            g = groups[pg.name]
-            self.callback.on_group_start(pg, i, len(active))
-            fp = (_data_fingerprint(g) if self.ckpt_dir is not None
-                  else "")
-            res = self._restore_group(pg, g, fp)
-            restored = res is not None
-            if res is None:
-                ctx = plan.group_context(pg)
-                res = run_fn(pg.rule.method, g, pg.rule.pattern, ctx)
-                if not masks_lib.validate_mask(res.masks, pg.rule.pattern):
-                    raise ValueError(
-                        f"refiner {pg.rule.method!r} produced masks "
-                        f"violating {pg.rule.pattern_str!r} at group "
-                        f"{pg.name!r}")
-                self._save_group(pg, i, res, fp)
-            site_masks[g.name] = res.masks
-            rep = SiteReport(
-                name=g.name, labels=g.labels(),
-                loss_init=jnp.sum(res.loss_init, axis=1),
-                loss_final=jnp.sum(res.loss_final, axis=1),
-                swaps=jnp.sum(res.swaps, axis=1),
-                pattern=pg.rule.pattern_str, method=pg.rule.method)
-            reports.append(rep)
-            if res.new_weights is not None:
-                _write_updated_weights(new_params, g, res.new_weights)
-            self.callback.on_group_done(pg, rep, restored=restored)
-
-        mask_tree = sites_lib.build_mask_tree(
-            self.api.cfg, site_masks, [groups[pg.name] for pg in active])
-        # skip rules may empty a whole top-level family the models index
-        # directly (masks["layers"], ...) — keep those keys present. The
-        # family tables define group names mirroring param paths, so the
-        # first dotted component IS the top-level tree key.
-        for pg in plan.groups:
-            mask_tree.setdefault(pg.spec.name.split(".", 1)[0], {})
+            with trace.span("prune.assemble"):
+                mask_tree = sites_lib.build_mask_tree(
+                    self.api.cfg, site_masks,
+                    [groups[pg.name] for pg in active])
+                # skip rules may empty a whole top-level family the models
+                # index directly (masks["layers"], ...) — keep those keys
+                # present. The family tables define group names mirroring
+                # param paths, so the first dotted component IS the
+                # top-level tree key.
+                for pg in plan.groups:
+                    mask_tree.setdefault(pg.spec.name.split(".", 1)[0], {})
+            trace.wait(mask_tree, "prune.masks")
 
         report = PruneReport(
             masks=mask_tree,
@@ -394,13 +398,61 @@ class PruneExecutor:
             method=_summarize([pg.rule.method for pg in active]),
             warmstart=_summarize([pg.rule.warmstart for pg in active]),
             pattern=_summarize([pg.rule.pattern_str for pg in active]),
-            wall_time_s=time.time() - t_start,
+            wall_time_s=run_span.seconds,
             updated_params=new_params,
             plan=plan,
         )
         self._last_report = report
         self.callback.on_run_done(report)
         return report
+
+    def _calibrate(self, calib_batches) -> None:
+        """Streaming, skip-aware, donated-carry accumulation; batches
+        shard over the plan's mesh when they divide its data axes."""
+        spec = (self.calib_spec if self.calib_spec is not None
+                else self.plan.calib_spec(minimal=False))
+        self.stats = stats_lib.accumulate_stats(
+            self.api, self.params, calib_batches, spec=spec,
+            mesh=self.plan.mesh,
+            ckpt_dir=(self.ckpt_dir / "calib"
+                      if self.ckpt_dir is not None else None),
+            checkpoint_every=self.calib_ckpt_every)
+        self.taps = self.stats.taps
+
+    def _group(self, pg: plan_lib.PlannedGroup, g: sites_lib.SiteGroup,
+               index: int, span) -> tuple[engine_lib.GroupResult, bool]:
+        """One group's result, restored from its checkpoint or refined,
+        validated and checkpointed; ``span`` takes its counts."""
+        fp = _data_fingerprint(g) if self.ckpt_dir is not None else ""
+        res = self._restore_group(pg, g, fp)
+        if res is not None:
+            return res, True
+        run_fn = {"batched": engine_lib.refine_group,
+                  "reference": engine_lib.refine_group_reference}[
+                      self.engine_mode]
+        ctx = self.plan.group_context(pg)
+        counting = trace.enabled()
+        with trace.span("prune.refine"), (
+                sparseswaps.count_search_passes() if counting
+                else contextlib.nullcontext()) as cnt:
+            res = run_fn(pg.rule.method, g, pg.rule.pattern, ctx)
+        pattern = pg.rule.pattern
+        if not trace.wait(res.masks, "prune.check",
+                          partial(masks_lib.validate_mask, pattern=pattern)):
+            raise ValueError(
+                f"refiner {pg.rule.method!r} produced masks "
+                f"violating {pg.rule.pattern_str!r} at group "
+                f"{pg.name!r}")
+        if counting:
+            span.set(passes=cnt.passes, rows_scored=cnt.rows_scored,
+                     swaps=int(trace.wait(jnp.sum(res.swaps), "prune.swaps",
+                                          np.asarray)))
+            if pg.rule.method == "sparseswaps":
+                d = g.weights.shape[-1]
+                span.set(k=sparseswaps._pick_k(ctx.k_swaps, d,
+                                               pattern.block(d)))
+        self._save_group(pg, index, res, fp)
+        return res, False
 
     # -- post-prune recovery ------------------------------------------------
 
@@ -496,7 +548,8 @@ def changed_leaves(base: dict, new: dict) -> dict:
     for (bpath, bleaf), (_, nleaf) in zip(base_flat, new_flat):
         if nleaf is bleaf:
             continue
-        if np.array_equal(np.asarray(nleaf), np.asarray(bleaf)):
+        if np.array_equal(*trace.wait((nleaf, bleaf), "prune.export",
+                                      jax.device_get)):
             continue
         out[".".join(str(p.key) for p in bpath)] = nleaf
     return out

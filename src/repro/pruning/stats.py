@@ -54,6 +54,7 @@ from repro.core import gram as gram_lib
 from repro.dist import specs as specs_lib
 from repro.models import ModelApi
 from repro.models import common as common_lib
+from repro.runtime import trace
 
 from . import sites as sites_lib
 
@@ -250,12 +251,12 @@ def make_tap_step(api: ModelApi, spec: CalibSpec):
     policy = spec.policy()
 
     @jax.jit
-    def step(params, batch):
+    def calib_step(params, batch):
         with common_lib.use_tap_policy(policy):
             _, aux = api.loss(params, batch, masks=None, want_taps=True)
         return aux["taps"]
 
-    return step
+    return calib_step
 
 
 def make_carry_step(api: ModelApi, spec: CalibSpec, *, donate: bool = True,
@@ -275,12 +276,12 @@ def make_carry_step(api: ModelApi, spec: CalibSpec, *, donate: bool = True,
 
     @partial(jax.jit, donate_argnums=(1,) if donate else (),
              out_shardings=out_shardings)
-    def step(params, state, batch):
+    def calib_step(params, state, batch):
         with common_lib.use_tap_policy(policy):
             _, aux = api.loss(params, batch, masks=None, want_taps=True)
         return jax.tree.map(jnp.add, state, aux["taps"])
 
-    return step
+    return calib_step
 
 
 def _dp_size(mesh: Mesh) -> int:
@@ -335,10 +336,10 @@ def make_sharded_step(api: ModelApi, spec: CalibSpec, mesh: Mesh,
                           out_specs=P(), check_vma=False)
 
     @partial(jax.jit, donate_argnums=(1,), out_shardings=state_shardings)
-    def step(params, state, batch):
+    def calib_step(params, state, batch):
         return jax.tree.map(jnp.add, state, local(params, batch))
 
-    return step
+    return calib_step
 
 
 def init_state(api: ModelApi, spec: CalibSpec, params, batch):
@@ -432,11 +433,12 @@ def accumulate_stats(api: ModelApi, params, batches, *,
     for i, batch in enumerate(replay()):
         if i < start:
             continue
-        state = step(params, state, batch)
-        done = i + 1
-        if (ckpt_dir is not None and checkpoint_every
-                and done % checkpoint_every == 0):
-            ckpt.save(ckpt_dir, done, state,
-                      extra={"calib_spec": spec.fingerprint()})
-            ckpt.gc(ckpt_dir, keep=1)
+        with trace.span("prune.calib.batch"):
+            state = step(params, state, batch)
+            done = i + 1
+            if (ckpt_dir is not None and checkpoint_every
+                    and done % checkpoint_every == 0):
+                ckpt.save(ckpt_dir, done, state,
+                          extra={"calib_spec": spec.fingerprint()})
+                ckpt.gc(ckpt_dir, keep=1)
     return CalibStats(taps=state, spec=spec, batches=done)

@@ -1,4 +1,5 @@
-"""Fault-tolerance runtime: retries, heartbeats, preemption, stragglers."""
+"""Runtime support: fault tolerance (retries, heartbeats, preemption,
+stragglers) and program spans (``runtime.trace``)."""
 from .fault_tolerance import Heartbeat, PreemptionGuard, StragglerMonitor, retry
 
 __all__ = ["Heartbeat", "PreemptionGuard", "StragglerMonitor", "retry"]

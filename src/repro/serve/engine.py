@@ -41,6 +41,7 @@ from repro.core import packed as packed_lib
 from repro.dist import specs as specs_lib
 from repro.kernels import spmm
 from repro.models import ModelApi, common
+from repro.runtime import trace
 from repro.serve import sampling as sampling_lib
 
 FORMATS = ("dense", "masked", "nm24", "gathered")
@@ -204,7 +205,7 @@ class ServeEngine:
         key = (n_steps, want_logits, sampled)
         if key not in self._scans:
             api = self.api      # the jit closes over api, never self
-            def run(params, masks, tok0, cache, samp):
+            def decode_scan(params, masks, tok0, cache, samp):
                 def step(carry, _):
                     tok, cache = carry
                     logits, cache = api.decode_step(
@@ -226,7 +227,7 @@ class ServeEngine:
                                               length=n_steps)
                 return ys
 
-            self._scans[key] = jax.jit(run)
+            self._scans[key] = jax.jit(decode_scan)
         return self._scans[key]
 
     def _greedy_loop(self, prompt: dict, n_new: int, *,
@@ -276,10 +277,10 @@ class ServeEngine:
                 tok0 = sampling_lib.sample_tokens(
                     logits0[:, -1], samp["temp"], samp["top_p"],
                     samp["top_k"], samp["seed"], jnp.int32(S))
-            jax.block_until_ready(tok0)
+            trace.wait(tok0, "engine.generate.prefill")
             t1 = time.time()
             rec_d: list = []
-            trace = None
+            logit_trace = None
             if n_new > 1:
                 # the whole decode loop is ONE scanned dispatch — the
                 # timed phase measures graph cost, not n_new-1 python
@@ -292,15 +293,15 @@ class ServeEngine:
                 out = jnp.concatenate([tok0[:, None], toks.T], axis=1)
             else:
                 out, logit_steps = tok0[:, None], None
-            jax.block_until_ready(out)
+            trace.wait(out, "engine.generate.decode")
             t2 = time.time()
         self._note_kernels("prefill", rec_p)
         self._note_kernels("decode", rec_d)
         if want_logits:
             first = logits0[:, -1].astype(jnp.float32)[None]
-            trace = first if logit_steps is None else \
+            logit_trace = first if logit_steps is None else \
                 jnp.concatenate([first, logit_steps], axis=0)
-        return out, trace, t1 - t0, t2 - t1
+        return out, logit_trace, t1 - t0, t2 - t1
 
     def _note_kernels(self, phase: str, rec: list) -> None:
         if rec:
@@ -358,10 +359,10 @@ class ServeEngine:
         """
         self._require_continuous()
         s_bucket = tokens.shape[1]
-        key = ("prefill_session", s_bucket)
-        if key not in self._fns:
-            api = self.api      # the jit closes over api, never self
-            def fn(params, masks, tokens, n_valid, samp):
+        api = self.api      # the jit closes over api, never self
+
+        def build():
+            def prefill_session(params, masks, tokens, n_valid, samp):
                 cache = api.init_cache(params, 1, s_bucket)
                 logits, cache = api.prefill(
                     params, {"tokens": tokens, "n_valid": n_valid}, cache,
@@ -372,16 +373,10 @@ class ServeEngine:
                 kv = cache.kv
                 return tok0, kv.k[:, 0], kv.v[:, 0]
 
-            self._fns[key] = jax.jit(fn)
-        with self._ctx(), common.use_matmul_policy(self._policy):
-            if self.dispatch_hook is not None:
-                self.dispatch_hook("prefill")
-            with spmm.record_dispatch() as rec:
-                out = self._fns[key](self.params, self.masks, tokens,
-                                     jnp.int32(n_valid), samp)
-            jax.block_until_ready(out[0])
-        self._note_kernels("prefill", rec)
-        return out
+            return jax.jit(prefill_session)
+
+        return self._call("prefill", ("prefill_session", s_bucket), build,
+                          tokens, jnp.int32(n_valid), samp)
 
     def prefill_chunk(self, tokens: jnp.ndarray, offset: int, n_valid: int,
                       cache, samp: dict):
@@ -410,10 +405,11 @@ class ServeEngine:
                 f"{self.cfg.name!r} has no windowed-prefill continuation")
         w = tokens.shape[1]
         capacity = cache.kv.k.shape[2]
-        key = ("prefill_chunk", w, capacity)
-        if key not in self._fns:
-            api = self.api      # the jit closes over api, never self
-            def fn(params, masks, tokens, offset, n_valid, cache, samp):
+        api = self.api      # the jit closes over api, never self
+
+        def build():
+            def prefill_chunk(params, masks, tokens, offset, n_valid, cache,
+                              samp):
                 logits, cache = api.prefill_window(
                     params, {"tokens": tokens, "offset": offset,
                              "n_valid": n_valid}, cache, masks=masks)
@@ -422,17 +418,11 @@ class ServeEngine:
                     samp["top_k"], samp["seed"], n_valid)
                 return tok0, cache
 
-            self._fns[key] = jax.jit(fn, donate_argnums=5)
-        with self._ctx(), common.use_matmul_policy(self._policy):
-            if self.dispatch_hook is not None:
-                self.dispatch_hook("prefill")
-            with spmm.record_dispatch() as rec:
-                tok0, cache = self._fns[key](
-                    self.params, self.masks, tokens, jnp.int32(offset),
-                    jnp.int32(n_valid), cache, samp)
-            jax.block_until_ready(tok0)
-        self._note_kernels("prefill", rec)
-        return tok0, cache
+            return jax.jit(prefill_chunk, donate_argnums=5)
+
+        return self._call("prefill", ("prefill_chunk", w, capacity), build,
+                          tokens, jnp.int32(offset), jnp.int32(n_valid),
+                          cache, samp)
 
     def decode_chunk(self, tok: jnp.ndarray, cache, active: jnp.ndarray,
                      samp: dict, *, n_steps: int, bucket: int):
@@ -453,10 +443,10 @@ class ServeEngine:
         self._require_continuous()
         from repro.models import attention as attn
         from repro.models.transformer import DecodeCache
-        key = ("chunk", n_steps, bucket)
-        if key not in self._fns:
-            api = self.api      # the jit closes over api, never self
-            def fn(params, masks, tok, cache, active, samp):
+        api = self.api      # the jit closes over api, never self
+
+        def build():
+            def decode_chunk(params, masks, tok, cache, active, samp):
                 kv = cache.kv
                 sub = DecodeCache(
                     kv=attn.KVCache(kv.k[:, :bucket], kv.v[:, :bucket],
@@ -487,16 +477,31 @@ class ServeEngine:
                     kv=kv_out, cross_kv=None,
                     t=cache.t.at[:bucket].set(sub.t))
 
-            self._fns[key] = jax.jit(fn, donate_argnums=3)
-        with self._ctx(), common.use_matmul_policy(self._policy):
+            return jax.jit(decode_chunk, donate_argnums=3)
+
+        return self._call("decode", ("chunk", n_steps, bucket), build,
+                          tok, cache, active, samp)
+
+    def _call(self, phase: str, key, build, *args):
+        """Run the program for ``key`` on ``args`` and wait for its first
+        output, inside an ``engine.<phase>`` span. ``build()`` makes the
+        program on first use; that call, which compiles, is an
+        ``engine.build`` span."""
+        fn = self._fns.get(key)
+        with self._ctx(), common.use_matmul_policy(self._policy), \
+                trace.span("engine." + phase):
             if self.dispatch_hook is not None:
-                self.dispatch_hook("decode")
+                self.dispatch_hook(phase)
             with spmm.record_dispatch() as rec:
-                toks, cache = self._fns[key](self.params, self.masks, tok,
-                                             cache, active, samp)
-            jax.block_until_ready(toks)
-        self._note_kernels("decode", rec)
-        return toks, cache
+                if fn is None:
+                    fn = self._fns[key] = build()
+                    with trace.span("engine.build", key=repr(key)):
+                        out = fn(self.params, self.masks, *args)
+                else:
+                    out = fn(self.params, self.masks, *args)
+            trace.wait(out[0], "engine." + phase)
+        self._note_kernels(phase, rec)
+        return out
 
     def compiled_fn_keys(self) -> list:
         """Keys of the scheduler-facing compiled fns (jit-churn tests)."""

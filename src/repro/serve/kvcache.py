@@ -51,6 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import common
+from repro.runtime import trace
 
 
 @dataclasses.dataclass
@@ -319,8 +320,8 @@ class PagedKVCache:
         """
         sess = self._table[sid]
         pids = self._padded_pids(sess, sess.length, capacity)
-        k = np.asarray(self.k[:, pids])
-        v = np.asarray(self.v[:, pids])
+        k, v = trace.wait((self.k[:, pids], self.v[:, pids]), "kv.spill",
+                          jax.device_get)
         out = HostSpill(sid=sid, length=sess.length, k=k, v=v)
         self.spilled_bytes_out += self.pages_for(sess.length) * self.page_bytes
         self.free(sid)

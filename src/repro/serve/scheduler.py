@@ -111,6 +111,7 @@ import numpy as np
 from repro.models import attention as attn
 from repro.models.transformer import DecodeCache
 from repro.runtime import fault_tolerance as ft
+from repro.runtime import trace
 
 from . import sampling as sampling_lib
 from .engine import ServeEngine, next_pow2
@@ -152,17 +153,15 @@ class StepEvents:
     prefilled: list               # rids whose first token appeared
     tokens: dict                  # rid -> [new token ids] this step
     completed: list               # Completion
-    n_active: int
-    n_queued: int
     prefill_started: list = dataclasses.field(default_factory=list)
     wasted_decode_tokens: int = 0  # decode steps discarded past budgets
-    # wall time spent in each lane this step — when the pools live on
-    # disjoint mesh slices the lanes run on disjoint devices, so a load
-    # generator may clock them on separate timelines
+    # wall time spent in each lane this step (the ``sched.prefill`` span;
+    # the ``sched.join`` and ``sched.decode`` spans) — when the pools
+    # live on disjoint mesh slices the lanes run on disjoint devices, so
+    # a load generator may clock them on separate timelines
     prefill_lane_s: float = 0.0
     decode_lane_s: float = 0.0
     expired: list = dataclasses.field(default_factory=list)   # rids
-    evicted: list = dataclasses.field(default_factory=list)   # sids
 
 
 @dataclasses.dataclass
@@ -686,7 +685,7 @@ class ContinuousScheduler:
         # compact — the request parks in _evicted until pages free up
         k, v = _read_slot(self.cache, jnp.int32(b))
         self.pool.store(slot.sid, k, v, slot.t_true)
-        tok = int(jax.device_get(self._toks)[b])
+        tok = int(trace.wait(self._toks, "sched.evict", jax.device_get)[b])
         spill = self.pool.spill(slot.sid, capacity=self.capacity)
         self._compact_remove(b)
         self._evicted.append(_Evicted(slot=slot, spill=spill, tok=tok))
@@ -798,6 +797,7 @@ class ContinuousScheduler:
                 jnp.asarray(padded), S, sampling_lib.params_arrays([samp]))
             (self.prefill_pool if self.disaggregate
              else self.pool).store(sid, k, v, S)
+            tok0 = trace.wait(tok0, "sched.prefill.first", np.asarray)
             self._finish_prefill(rid, sid, S, max_new, samp, keep,
                                  int(tok0[0]), events, req.deadline)
             return
@@ -824,6 +824,7 @@ class ContinuousScheduler:
         (self.prefill_pool if self.disaggregate else self.pool).store(
             pf.sid, pf.cache.kv.k[:, 0], pf.cache.kv.v[:, 0], pf.S)
         pf.cache = None                          # drop the B=1 carrier
+        tok = trace.wait(tok, "sched.prefill.first", np.asarray)
         self._finish_prefill(pf.rid, pf.sid, pf.S, pf.max_new, pf.samp,
                              pf.keep, int(tok[0]), events, pf.deadline)
 
@@ -989,61 +990,78 @@ class ContinuousScheduler:
 
     def step(self) -> StepEvents:
         """One scheduler step: expiry sweep, up to ``prefill_budget``
-        prefill-lane units, ready-session joins, then one decode
-        chunk."""
+        prefill-lane units, ready-session joins, then one decode chunk.
+
+        Spans (``runtime.trace``): ``sched.step`` — attrs ``n_active``
+        (rows decoded), ``bucket`` and ``n_steps`` (the chunk's shape),
+        ``wasted`` (its discarded row-steps) and ``n_queued`` (requests
+        waiting once the step ends) — around ``sched.expire``,
+        ``sched.prefill``, ``sched.join`` and ``sched.decode``.
+        """
         self._step_no += 1
         if self._injector is not None:
             self._injector.begin_step(self._step_no)
         if self.guard is not None and self.guard.should_save:
             self.draining = True
-        events = StepEvents(prefilled=[], tokens={}, completed=[],
-                            n_active=0, n_queued=0)
-        self._expire(events, self._now())
-        t0 = time.perf_counter()
-        for _ in range(self.prefill_budget):
-            if not self._prefill_one(events):
-                break
-        t1 = time.perf_counter()
-        events.prefill_lane_s = t1 - t0
-        # shipping scatters into the decode pool, so it bills decode
-        self._join_ready(events)
+        events = StepEvents(prefilled=[], tokens={}, completed=[])
+        with trace.span("sched.step") as step:
+            with trace.span("sched.expire"):
+                self._expire(events, self._now())
+            with trace.timed("sched.prefill") as prefill:
+                for _ in range(self.prefill_budget):
+                    if not self._prefill_one(events):
+                        break
+            # shipping scatters into the decode pool, so it bills decode
+            with trace.timed("sched.join") as join:
+                self._join_ready(events)
+            events.decode_lane_s = join.seconds
+            if self.slots:
+                with trace.timed("sched.decode") as decode:
+                    self._decode(events, step)
+                events.decode_lane_s += decode.seconds
+            step.set(n_queued=len(self.queue) + len(self._inflight)
+                     + len(self._ready) + len(self._evicted))
+        events.prefill_lane_s = prefill.seconds
+        return events
+
+    def _decode(self, events: StepEvents, step) -> None:
+        """One decode chunk over the active rows, then their leaves."""
         n_active = len(self.slots)
-        if n_active:
-            # clamp to the pow2 bucket of the largest remaining budget —
-            # exact clamping would compile up to decode_chunk distinct
-            # chunk programs; the bucket keeps it to log2 like the batch
-            # dimension, while a tail of short requests stops paying for
-            # whole chunks of discarded steps
-            n_steps = min(self.decode_chunk,
-                          next_pow2(max(s.rem for s in self.slots)))
-            bucket = min(next_pow2(n_active), self.max_batch) \
-                if self.bucket_batch else self.max_batch
+        # clamp to the pow2 bucket of the largest remaining budget —
+        # exact clamping would compile up to decode_chunk distinct
+        # chunk programs; the bucket keeps it to log2 like the batch
+        # dimension, while a tail of short requests stops paying for
+        # whole chunks of discarded steps
+        n_steps = min(self.decode_chunk,
+                      next_pow2(max(s.rem for s in self.slots)))
+        bucket = min(next_pow2(n_active), self.max_batch) \
+            if self.bucket_batch else self.max_batch
+        with trace.span("sched.decode.upload"):
             active = jnp.arange(self.max_batch) < n_active
             samp = {k: jnp.asarray(v) for k, v in self._samp.items()}
-            toks, self.cache = self.engine.decode_chunk(
-                self._toks, self.cache, active, samp,
-                n_steps=n_steps, bucket=bucket)
-            self._toks = self._toks.at[:bucket].set(toks[-1])
-            host = np.asarray(toks)              # (n_steps, bucket)
-            for b, slot in enumerate(self.slots):
-                m = min(n_steps, slot.rem)
-                events.wasted_decode_tokens += n_steps - m
-                new = host[:m, b].tolist()
-                slot.emitted.extend(new)
-                slot.rem -= m
-                slot.t_true += m
-                self._last_used[slot.sid] = self._step_no
-                events.tokens.setdefault(slot.rid, []).extend(new)
-            # leave in reverse so swap-remove never disturbs an earlier
-            # finished row we have yet to process
-            for b in range(len(self.slots) - 1, -1, -1):
-                if self.slots[b].rem == 0:
+        toks, self.cache = self.engine.decode_chunk(
+            self._toks, self.cache, active, samp,
+            n_steps=n_steps, bucket=bucket)
+        self._toks = self._toks.at[:bucket].set(toks[-1])
+        # (n_steps, bucket) on the host
+        host = trace.wait(toks, "sched.decode.fetch", np.asarray)
+        for b, slot in enumerate(self.slots):
+            m = min(n_steps, slot.rem)
+            events.wasted_decode_tokens += n_steps - m
+            new = host[:m, b].tolist()
+            slot.emitted.extend(new)
+            slot.rem -= m
+            slot.t_true += m
+            self._last_used[slot.sid] = self._step_no
+            events.tokens.setdefault(slot.rid, []).extend(new)
+        step.set(n_active=n_active, bucket=bucket, n_steps=n_steps,
+                 wasted=events.wasted_decode_tokens)
+        # leave in reverse so swap-remove never disturbs an earlier
+        # finished row we have yet to process
+        for b in range(len(self.slots) - 1, -1, -1):
+            if self.slots[b].rem == 0:
+                with trace.span("sched.leave", rid=self.slots[b].rid):
                     events.completed.append(self._leave(b))
-        events.n_active = len(self.slots)
-        events.n_queued = (len(self.queue) + len(self._inflight)
-                           + len(self._ready) + len(self._evicted))
-        events.decode_lane_s = time.perf_counter() - t1
-        return events
 
     @property
     def idle(self) -> bool:
