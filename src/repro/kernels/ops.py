@@ -73,6 +73,25 @@ def _pad_swap_inputs(w, m, c, G, row_block: int, tile: int):
     return a, b, w32, G32, tile
 
 
+def _lane_tile(dp: int, cap: int) -> int:
+    """Largest multiple of 128 lanes that divides ``dp``, at most ``cap``."""
+    return max(t for t in range(128, min(cap, dp) + 1, 128) if dp % t == 0)
+
+
+def topk_tiles(d: int) -> tuple[int, int]:
+    """(u, p) tiles of the fused top-k search for inputs of width d.
+
+    d pads as for the argmin kernel (to 256, or 128 below that) and never
+    further: the tiles are the widest lane multiples that divide the
+    padded width, so fewer grid steps never cost padded work. A long
+    u-tile amortises each row's sublane fold; 1024 bounds its spreads in
+    VMEM. A p-tile is one sweep a row, its running state in registers up
+    to 512 lanes; on a v5e that beat wider p-tiles swept in chunks.
+    """
+    dp = _round_up(d, min(256, _round_up(d, 128)))
+    return _lane_tile(dp, 1024), _lane_tile(dp, 512)
+
+
 def swap_topk(
     w: jnp.ndarray,
     m: jnp.ndarray,
@@ -81,7 +100,6 @@ def swap_topk(
     *,
     k: int,
     row_block: int = 8,
-    tile: int = 256,
     interpret: bool | None = None,
 ):
     """k best candidate swaps per row: (ΔL, u, p) each (R, k), fused.
@@ -95,10 +113,13 @@ def swap_topk(
     if interpret is None:
         interpret = not _on_tpu()
     R, d = w.shape
-    a, b, w32, G32, tile = _pad_swap_inputs(w, m, c, G, row_block, tile)
+    tile_u, tile_p = topk_tiles(d)
+    a, b, w32, G32, _ = _pad_swap_inputs(w, m, c, G, row_block, 256)
+    # the kernel scores (a + b) + (c·w_p)·g with c = -2·w: doubling and
+    # negation are exact, so this is a + b - 2·(w_u·w_p)·g bit for bit
     vals, u, p = swap_topk_padded(
-        a, b, w32, G32, k=k, row_block=row_block, tile_u=tile, tile_p=tile,
-        interpret=interpret,
+        a, b, -2.0 * w32, w32, G32, k=k, row_block=row_block, tile_u=tile_u,
+        tile_p=tile_p, interpret=interpret,
     )
     return (vals[:R], jnp.minimum(u[:R], d - 1), jnp.minimum(p[:R], d - 1))
 
@@ -112,7 +133,6 @@ def swap_topk_commit(
     k: int,
     eps: float = 0.0,
     row_block: int = 8,
-    tile: int = 256,
     interpret: bool | None = None,
 ):
     """One fused k-swap refinement step on the Pallas path.
@@ -127,7 +147,7 @@ def swap_topk_commit(
     if interpret is None:
         interpret = not _on_tpu()
     R = w.shape[0]
-    dl, u, p = swap_topk(w, m, c, G, k=k, row_block=row_block, tile=tile,
+    dl, u, p = swap_topk(w, m, c, G, k=k, row_block=row_block,
                          interpret=interpret)
     c32 = c.astype(jnp.float32)
     valid = jnp.isfinite(dl).astype(jnp.float32)

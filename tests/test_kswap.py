@@ -19,6 +19,7 @@ monotone swaps. These tests pin the contract:
   deterministic count, not wall-clock.
 """
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -83,6 +84,110 @@ def test_topk_candidates_feasible_and_sorted():
         assert len(set(p[r][fin])) == fin.sum()          # distinct p
         for j in np.where(fin)[0]:
             assert m_np[r, u[r, j]] == 1.0 and m_np[r, p[r, j]] == 0.0
+
+
+def _tiled_problem(R, d, *, planted, seed=0):
+    """Rows, Gram and mask at a width the search splits into many tiles.
+
+    With ``planted``, two kept columns in different u-tiles and two pruned
+    columns in different p-tiles are exact duplicates (same Gram row and
+    column, same w and c), so both pairs tie on every ΔL they enter. The
+    pruned pair carries large weights, so it heads every row's list, and
+    is anti-correlated with the kept pair, so pruning that pair is its
+    best swap. Row 0 prunes only three columns: fewer than k finite
+    candidates.
+    """
+    rng = np.random.default_rng(seed)
+    X = rng.normal(size=(d, 64)).astype(np.float32)
+    G = X @ X.T / 64 + np.eye(d, dtype=np.float32)
+    W = rng.normal(size=(R, d)).astype(np.float32)
+    m = (rng.random((R, d)) > 0.6).astype(np.float32)
+    c = None
+    if planted:
+        tu, tp = kops.topk_tiles(d)
+        tie_u, tie_p = (3, 3 + 2 * tu), (tu + 5, tu + 5 + tp)
+        m[0] = 1.0
+        m[0, 7] = 0.0
+        for (j1, j2), keep in ((tie_u, 1.0), (tie_p, 0.0)):
+            G[:, j2] = G[:, j1]
+            G[j2, :] = G[j1, :]
+            W[:, j2] = W[:, j1]
+            m[:, (j1, j2)] = keep
+        for jp in tie_p:
+            G[tie_u, jp] = G[jp, tie_u] = -4.0
+        W[:, tie_p] = 8.0
+        W[:, tie_u] = 1.0
+        c = np.array(sm.correlation_vector(W, m, G))
+        for j1, j2 in (tie_u, tie_p):
+            c[:, j2] = c[:, j1]
+    W, G, m = jnp.asarray(W), jnp.asarray(G), jnp.asarray(m)
+    c = sm.correlation_vector(W, m, G) if c is None else jnp.asarray(c)
+    return W, G, m, c
+
+
+@pytest.mark.parametrize("R,d,planted", [(3, 3072, False), (3, 4608, True),
+                                         (11, 2304, True), (13, 600, False)])
+def test_topk_kernel_multi_tile_bitwise(R, d, planted):
+    """The Pallas search across many (u, p) tiles, a partial row block and
+    padded columns: u and p equal the dense reference exactly on feasible
+    entries, ties and short rows included. ΔL compares within the
+    single-tile test's rtol=1e-5: in interpret mode the CPU backend
+    contracts the kernel's ``(a + b) + (c·w)·g`` into a fused
+    multiply-add (one ulp off the reference at d = 600), which the TPU's
+    vector unit does not."""
+    k = 8
+    W, G, m, c = _tiled_problem(R, d, planted=planted)
+    tu, tp = kops.topk_tiles(d)
+    dp = -(-d // 256) * 256
+    if d >= 3072:                     # at least 3 u-tiles and 3 p-tiles
+        assert dp // tu >= 3 and dp // tp >= 3, (tu, tp)
+    # the dense reference row by row: its (1, d, d) ΔL stays small
+    ref = [sm.topk_swaps_dense(W[r:r + 1], m[r:r + 1], c[r:r + 1], G, k=k)
+           for r in range(R)]
+    vd, ud, pd = (np.concatenate([np.asarray(x[i]) for x in ref])
+                  for i in range(3))
+    vk, uk, pk = (np.asarray(x) for x in
+                  kops.swap_topk(W, m, c, G, k=k, interpret=True))
+    fin = np.isfinite(vd)
+    assert np.array_equal(np.isfinite(vk), fin)
+    np.testing.assert_allclose(vk[fin], vd[fin], rtol=1e-5, atol=1e-4)
+    assert np.array_equal(uk[fin], ud[fin])
+    assert np.array_equal(pk[fin], pd[fin])
+    if planted:
+        assert fin[0].sum() == 3                     # the short row
+        tie_u, tie_p = (3, 3 + 2 * tu), (tu + 5, tu + 5 + tp)
+        # the tied pruned pair heads each list, lower p first; equal ΔL
+        assert np.all(pk[:, :2] == np.array(tie_p))
+        assert np.array_equal(vk[:, 0], vk[:, 1])
+        # a tie in u resolves to the lower u, in the earlier u-tile
+        assert np.any(uk[fin] == tie_u[0])
+        assert not np.any(uk[fin] == tie_u[1])
+
+
+@pytest.mark.parametrize("d", [24, 128, 130, 300, 600, 2304, 3072, 4096,
+                               9216, 11008, 13696, 14336, 16384, 28672])
+def test_topk_tiles_divide_the_padded_width(d, monkeypatch):
+    """The search's tiles are lane multiples that divide the width padded
+    to 256 (128 below that), so they never pad it further; the wrapper
+    hands the kernel exactly that width."""
+    R = 20
+    dp = -(-d // 256) * 256 if d > 128 else 128
+    tu, tp = kops.topk_tiles(d)
+    for t in (tu, tp):
+        assert t % 128 == 0 and dp % t == 0
+    seen = {}
+
+    def spy(a, b, cc, w, g, *, k, row_block, tile_u, tile_p, interpret):
+        seen.update(shape=a.shape, g=g.shape, tiles=(row_block, tile_u,
+                                                     tile_p))
+        z = jnp.zeros((a.shape[0], k))
+        return z, z.astype(jnp.int32), z.astype(jnp.int32)
+
+    monkeypatch.setattr(kops, "swap_topk_padded", spy)
+    x = jax.ShapeDtypeStruct((R, d), jnp.float32)
+    jax.eval_shape(lambda w, mm, cc, g: kops.swap_topk(w, mm, cc, g, k=8),
+                   x, x, x, jax.ShapeDtypeStruct((d, d), jnp.float32))
+    assert seen == dict(shape=(24, dp), g=(dp, dp), tiles=(8, tu, tp))
 
 
 # ---------------------------------------------------------------------------

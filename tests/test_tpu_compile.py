@@ -5,7 +5,8 @@ refinement that calls one) for one chip of a *described* ``v5e:2x2``
 topology and compiles it with the TPU compiler, which refuses what
 interpret mode accepts — a lane slice not aligned to the tiling, more
 VMEM than a kernel may use. Shapes are minitron-4b's: d_model 3072,
-d_ff 9216, unstructured 0.6 (k = 0.4·d_in kept per row) and 2:4.
+d_ff 9216, unstructured 0.6 (k = 0.4·d_in kept per row) and 2:4; the
+swap search also at chatglm3-6b's 4096 and 13696.
 
 The topology is described inside module fixtures (only the worker that
 runs this file loads the TPU compiler), and the persistent compilation
@@ -87,14 +88,17 @@ def test_gram_compiles(compile_tpu):
     assert _has_kernel(c)
 
 
-def test_swap_topk_stacked_compiles(compile_tpu):
-    """The swap search over a stacked (N, R, d) down-proj group."""
+@pytest.mark.parametrize("d_model,d_ff", [(D_MODEL, D_FF), (4096, 13696)])
+def test_swap_topk_stacked_compiles(compile_tpu, d_model, d_ff):
+    """The swap search over a stacked (N, R, d) down-proj group, with the
+    tiles it picks at minitron-4b's widths and at chatglm3-6b's, whose
+    d_ff 13696 pads to 13824."""
     f32 = jnp.float32
     run = jax.vmap(lambda w, m, c, g: kops.swap_topk(w, m, c, g, k=8))
-    c = compile_tpu(run, ((N_LAYERS, D_MODEL, D_FF), f32),
-                    ((N_LAYERS, D_MODEL, D_FF), f32),
-                    ((N_LAYERS, D_MODEL, D_FF), f32),
-                    ((N_LAYERS, D_FF, D_FF), f32))
+    c = compile_tpu(run, ((N_LAYERS, d_model, d_ff), f32),
+                    ((N_LAYERS, d_model, d_ff), f32),
+                    ((N_LAYERS, d_model, d_ff), f32),
+                    ((N_LAYERS, d_ff, d_ff), f32))
     assert _has_kernel(c)
 
 
